@@ -46,6 +46,7 @@ from repro.parallel.sharded_router import (  # noqa: E402
     routed_step_roofline,
     sharded_route,
 )
+from repro.roofline.analysis import V5E, hw_for  # noqa: E402
 
 QUICK_SCALE = 0.1
 
@@ -92,7 +93,7 @@ def _route(keys, nc, n_workers, *, d_max, n_shards, sync_period, w_mode,
 
 def bit_exact_check(seed: int) -> bool:
     """sharded(n_shards=1, sync_period=1) vs the single-core Pallas routers
-    (interpret mode) — the tentpole's differential, also in
+    (compiled on TPU, interpreted elsewhere) — the differential also in
     tests/test_sharded_router.py."""
     from repro.kernels.adaptive_route import adaptive_route, w_route
 
@@ -109,10 +110,10 @@ def bit_exact_check(seed: int) -> bool:
         if w_mode:
             flags = (np.asarray(full) == int(W_SENTINEL)).astype(np.int32)
             a_k, l_k = w_route(keys, jnp.asarray(flags), W, d=d_max,
-                               chunk=n, block=BLOCK, interpret=True)
+                               chunk=n, block=BLOCK)
         else:
             a_k, l_k = adaptive_route(keys, full, W, d_max=d_max, chunk=n,
-                                      block=BLOCK, interpret=True)
+                                      block=BLOCK)
         ok = ok and bool(
             (np.asarray(a_s) == np.asarray(a_k)).all()
             and (np.asarray(l_s) == np.asarray(l_k[-1])).all()
@@ -200,10 +201,13 @@ def collect(scale: float = 1.0, seed: int = 0) -> dict:
             "imbalance": {"w": tradeoff[p]},
         }
 
+    # the roofline terms are modelled for the v5e target, whatever device
+    # compiled the program: its peaks are named, not assumed
     roofline = routed_step_roofline(
         W, n_shards=min(8, n_dev), sync_period=16, n_epochs=4, block=BLOCK,
-        d_max=2, w_mode=True,
+        d_max=2, w_mode=True, hw=hw_for(V5E),
     )
+    roofline["peaks_of"] = V5E
 
     return {
         "n_devices": n_dev,

@@ -11,12 +11,12 @@ Three scenarios on the BENCH_* JSON convention:
   ``events_per_sec_abs``.  Gated ``bytes_per_key`` is
   ``ChunkedRouter.state_bytes() / distinct keys`` — the flat-memory number:
   carried routing state is constant, so bytes/key shrinks as keys grow.
-* ``rss`` — two subprocess children route the same stream end to end, one
-  through the flat pipeline (generator in, per-chunk histogram out), one
+* ``rss`` — the same stream routed end to end twice in this process, once
+  through the flat pipeline (generator in, per-chunk histogram out), once
   through the materialize-everything pipeline (full key array in, full
-  assignment array out), and report their post-warmup RSS growth from
+  assignment array out), each reporting its post-warmup RSS growth from
   /proc/self/statm.  Gated ``rss_ratio`` = chunked growth / one-shot growth;
-  the ISSUE's hard ``rss_flat`` (ratio <= 0.5) check arms once the child
+  the hard ``rss_flat`` (ratio <= 0.5) check arms once the measured
   stream is >= 3e6 events (below that both growths are allocator noise) —
   the nightly --scale 50 run (1e7 events) exercises it.
 * ``trace_ingest`` — tools/make_trace.py fixtures in both real formats
@@ -35,10 +35,7 @@ the 1e7-event nightly tier and ``--scale 500`` the un-gated 1e6-key /
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 import warnings
@@ -132,54 +129,42 @@ def _chunked_stream_scenario(events: int, n_keys: int, seed: int) -> dict:
     return entry
 
 
-# -- RSS experiment (subprocess children; /proc/self/statm resident pages) --
+# -- RSS experiment (one process; /proc/self/statm resident pages) ----------
 
-_RSS_CHILD = r"""
-import json, os, sys
-import numpy as np
-from repro.core.streams import StreamSpec
-from repro.parallel.chunked_driver import ChunkedRouter
 
-mode, events, n_keys, seed = (
-    sys.argv[1], int(sys.argv[2]), int(sys.argv[3]), int(sys.argv[4])
-)
-
-def rss():
+def _rss_bytes() -> int:
     with open("/proc/self/statm") as f:
         return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
 
-CHUNK, W = 8192, 32
-spec = StreamSpec(name="rss", n_msgs=events, n_keys=n_keys, z=1.4)
-router = ChunkedRouter(W, "pkg", chunk=CHUNK, seed=seed)
-router.route_stream(np.zeros(CHUNK, np.int32))  # compile before baselining
-hist = np.zeros(W, np.int64)
-def on_chunk(a):
-    hist[:] = hist + np.bincount(a, minlength=W)
-base = rss()
-if mode == "chunked":
-    n = router.route_stream(spec.stream_chunks(CHUNK, seed=seed),
-                            on_chunk=on_chunk)
-else:  # materialize-everything pipeline: keys array in, assignments out
-    keys = np.concatenate(list(spec.stream_chunks(CHUNK, seed=seed)))
-    a = router.route_stream(keys)
-    hist[:] = hist + np.bincount(a, minlength=W)
-    n = len(a)
-growth = rss() - base
-print(json.dumps({"growth_mb": growth / 1e6, "events": int(n),
-                  "hist_sum": int(hist.sum())}))
-"""
 
+def _rss_run(mode: str, events: int, n_keys: int, seed: int) -> dict:
+    """Route one stream end to end in this process and report its RSS growth
+    while the pipeline's data is live.  "chunked" is the flat pipeline
+    (generator in, per-chunk histogram out); "oneshot" materializes
+    everything (key array in, assignment array out, both still held when
+    RSS is read).  Runs in the process that already holds the device: a
+    child would have to fight the parent for the chip."""
+    spec = StreamSpec(name="rss", n_msgs=events, n_keys=n_keys, z=Z)
+    router = ChunkedRouter(W, "pkg", chunk=CHUNK, seed=seed)
+    router.route_stream(np.zeros(CHUNK, np.int32))  # compile before baselining
+    hist = np.zeros(W, np.int64)
 
-def _rss_child(mode: str, events: int, n_keys: int, seed: int) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    root = Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = f"{root / 'src'}:{env.get('PYTHONPATH', '')}"
-    out = subprocess.run(
-        [sys.executable, "-c", _RSS_CHILD, mode, str(events), str(n_keys),
-         str(seed)],
-        capture_output=True, text=True, env=env, check=True,
-    )
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    def on_chunk(a):
+        hist[:] = hist + np.bincount(a, minlength=W)
+
+    base = _rss_bytes()
+    if mode == "chunked":
+        n = router.route_stream(spec.stream_chunks(CHUNK, seed=seed),
+                                on_chunk=on_chunk)
+        growth = _rss_bytes() - base
+    else:
+        keys = np.concatenate(list(spec.stream_chunks(CHUNK, seed=seed)))
+        a = router.route_stream(keys)
+        hist[:] = hist + np.bincount(a, minlength=W)
+        n = len(a)
+        growth = _rss_bytes() - base
+    return {"growth_mb": growth / 1e6, "events": int(n),
+            "hist_sum": int(hist.sum())}
 
 
 def _rss_scenario(events: int, n_keys: int, seed: int) -> tuple[dict, bool]:
@@ -187,8 +172,10 @@ def _rss_scenario(events: int, n_keys: int, seed: int) -> tuple[dict, bool]:
     # nightly tier (>= 3e6 after the cap) arms the hard rss_flat check
     rss_events = max(min(events, 10_000_000), 1_000_000)
     rss_keys = max(min(n_keys, rss_events // 100), 1000)
-    chk = _rss_child("chunked", rss_events, rss_keys, seed)
-    one = _rss_child("oneshot", rss_events, rss_keys, seed)
+    # chunked first: memory the one-shot run frees may stay mapped by the
+    # allocator and would be counted against a run measured after it
+    chk = _rss_run("chunked", rss_events, rss_keys, seed)
+    one = _rss_run("oneshot", rss_events, rss_keys, seed)
     assert chk["events"] == one["events"] == rss_events
     assert chk["hist_sum"] == one["hist_sum"] == rss_events
     # 1 MB floor: both numbers ride on allocator noise at that granularity

@@ -47,6 +47,7 @@ from benchmarks import (
     bench_trace_scale,
 )
 from benchmarks.common import bench_main
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("table2", bench_table2),
@@ -123,6 +124,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     if args.ci_set:
         run_ci_set(args.out, quick=args.quick,
@@ -136,11 +138,7 @@ def main() -> None:
         if only and name not in only:
             continue
         t0 = time.time()
-        try:
-            rows = mod.run(scale=scale)
-        except Exception as e:  # noqa: BLE001
-            print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
-            continue
+        rows = mod.run(scale=scale)
         for row in rows:
             print(row.csv(), flush=True)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr, flush=True)

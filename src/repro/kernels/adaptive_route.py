@@ -16,9 +16,10 @@ and routes by a *global* masked argmin over the full (1, n_workers) loads row
 (pad lanes hold the MASK sentinel, ties break to the lowest worker index), so
 n_workers need not be a power of two nor fit one VPU lane group.  The r-th
 head lane of a block takes the r-th argmin of the sequential water-fill of
-that row — computed loop-free by one stable sort (waterfill_picks) — so head
-messages reproduce w_choices_partition's global step exactly from block-start
-loads instead of piling a whole block onto a single stale minimum.
+that row (route_core.waterfill_assign: one masked argmin per head lane) — so
+head messages reproduce w_choices_partition's global step exactly from
+block-start loads instead of piling a whole block onto a single stale
+minimum.
 
 The per-block machinery (hash, one-hot load fetch, mask, argmin, water-fill,
 histogram update) all lives in kernels/route_core.py — ONE routing core
@@ -48,16 +49,12 @@ from repro.core.estimation import W_SENTINEL
 from repro.core.hashing import derive_seeds
 from repro.kernels.platform import resolve_interpret
 from repro.kernels.route_core import (
+    chunk_loads_spec,
+    chunk_rows_spec,
     hash_candidates,
     head_table_ncand,
     route_block,
-    waterfill_picks,
 )
-
-# Long-standing private names, re-exported for existing importers (tests,
-# ref.py): the implementations moved verbatim to route_core.
-_waterfill_picks = waterfill_picks
-_head_table_ncand = head_table_ncand
 
 
 def _kernel(keys_ref, ncand_ref, seeds_ref, *rest, n_workers, d_max, block,
@@ -68,19 +65,18 @@ def _kernel(keys_ref, ncand_ref, seeds_ref, *rest, n_workers, d_max, block,
     else:
         assign_ref, loads_ref = rest
         icap = None
-    chunk = keys_ref.shape[0]
-    nblk = chunk // block
+    nblk = keys_ref.shape[0]
     seeds = seeds_ref[...]  # (d_max,) uint32
 
     def body(i, loads):  # loads (1, n_workers) f32
-        kb = keys_ref[pl.ds(i * block, block)]  # (V,)
-        nc = ncand_ref[pl.ds(i * block, block)]  # (V,)
+        kb = keys_ref[pl.ds(i, 1), :].reshape(block)  # (V,)
+        nc = ncand_ref[pl.ds(i, 1), :].reshape(block)  # (V,)
         cand = hash_candidates(kb, seeds, n_workers)  # (V, d_max)
         choice, _, _, loads = route_block(
             cand, nc, loads, n_entities=n_workers, w_mode=w_mode,
             inv_cap=icap,
         )
-        assign_ref[pl.ds(i * block, block)] = choice
+        assign_ref[pl.ds(i, 1), :] = choice.reshape(1, block)
         return loads
 
     loads = lax.fori_loop(0, nblk, body, jnp.zeros((1, n_workers), jnp.float32))
@@ -132,12 +128,13 @@ def adaptive_route(
         has_cap=has_cap,
     )
     in_specs = [
-        pl.BlockSpec((chunk,), lambda i: (i,)),
-        pl.BlockSpec((chunk,), lambda i: (i,)),
+        chunk_rows_spec(chunk, block),
+        chunk_rows_spec(chunk, block),
         pl.BlockSpec((d_max,), lambda i: (0,)),
     ]
     operands = [
-        keys.astype(jnp.int32), n_cand.astype(jnp.int32),
+        keys.astype(jnp.int32).reshape(N // block, block),
+        n_cand.astype(jnp.int32).reshape(N // block, block),
         derive_seeds(seed, d_max),
     ]
     if has_cap:
@@ -149,16 +146,16 @@ def adaptive_route(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((1, n_workers), lambda i: (i, 0)),
+            chunk_rows_spec(chunk, block),
+            chunk_loads_spec(n_workers),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((N // chunk, n_workers), jnp.float32),
+            jax.ShapeDtypeStruct((N // block, block), jnp.int32),
+            jax.ShapeDtypeStruct((N // chunk, 1, n_workers), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(*operands)
-    return assign, loads
+    return assign.reshape(N), loads.reshape(N // chunk, n_workers)
 
 
 # ---------------------------------------------------------------------------
@@ -182,13 +179,12 @@ def _kernel_online(keys_ref, tblk_ref, tbln_ref, seeds_ref, *rest, n_workers,
     else:
         assign_ref, loads_ref = rest
         icap = None
-    chunk = keys_ref.shape[0]
-    nblk = chunk // block
+    nblk = keys_ref.shape[0]
     seeds = seeds_ref[...]  # (d_max,) uint32
     H = tblk_ref.shape[1]
 
     def body(i, loads):  # loads (1, n_workers) f32
-        kb = keys_ref[pl.ds(i * block, block)]  # (V,) int32
+        kb = keys_ref[pl.ds(i, 1), :].reshape(block)  # (V,) int32
         tk = tblk_ref[pl.ds(i, 1), :].reshape(H)  # (H,) int32 head-table keys
         tn = tbln_ref[pl.ds(i, 1), :].reshape(H)  # (H,) int32 head-table d(k)
         nc = head_table_ncand(kb, tk, tn, d_base, d_max)
@@ -197,7 +193,7 @@ def _kernel_online(keys_ref, tblk_ref, tbln_ref, seeds_ref, *rest, n_workers,
             cand, nc, loads, n_entities=n_workers, w_mode=w_mode,
             inv_cap=icap,
         )
-        assign_ref[pl.ds(i * block, block)] = choice
+        assign_ref[pl.ds(i, 1), :] = choice.reshape(1, block)
         return loads
 
     loads = lax.fori_loop(0, nblk, body, jnp.zeros((1, n_workers), jnp.float32))
@@ -249,13 +245,13 @@ def adaptive_route_online(
     )
     blocks_per_chunk = chunk // block
     in_specs = [
-        pl.BlockSpec((chunk,), lambda i: (i,)),
+        chunk_rows_spec(chunk, block),
         pl.BlockSpec((blocks_per_chunk, H), lambda i: (i, 0)),
         pl.BlockSpec((blocks_per_chunk, H), lambda i: (i, 0)),
         pl.BlockSpec((d_max,), lambda i: (0,)),
     ]
     operands = [
-        keys.astype(jnp.int32),
+        keys.astype(jnp.int32).reshape(N // block, block),
         tbl_keys.astype(jnp.int32),
         tbl_ncand.astype(jnp.int32),
         derive_seeds(seed, d_max),
@@ -269,16 +265,16 @@ def adaptive_route_online(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((1, n_workers), lambda i: (i, 0)),
+            chunk_rows_spec(chunk, block),
+            chunk_loads_spec(n_workers),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((N // chunk, n_workers), jnp.float32),
+            jax.ShapeDtypeStruct((N // block, block), jnp.int32),
+            jax.ShapeDtypeStruct((N // chunk, 1, n_workers), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(*operands)
-    return assign, loads
+    return assign.reshape(N), loads.reshape(N // chunk, n_workers)
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ through the SAME route_block core as the stream routers
 (kernels/route_core.py): candidate loads are fetched with a one-hot matmul,
 the lane-wise argmin picks the less-loaded candidate, and the block histogram
 updates the load vector — no gathers or scatters.  The winning candidate
-column (`sel`) gathers the matching gate weight.
+column (`sel`) selects the matching gate weight.
 
 The adaptive variant is the MoE incarnation of adaptive_route_online: each
 block reads a head-table snapshot of the *expert-popularity* SPACESAVING
@@ -37,12 +37,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.platform import resolve_interpret
-from repro.kernels.route_core import head_table_ncand, route_block
+from repro.kernels.route_core import head_table_ncand, pick_lane, route_block
 
 
 def _dispatch_block(cand, gate, nc, loads, *, n_experts, w_mode):
     """One token block through the shared core: flatten (blk, k, C) slot
-    candidates into blk*k lanes, route, gather the winning gate per lane.
+    candidates into blk*k lanes, route, select the winning gate per lane.
     Returns (idx (blk,k), gsel (blk,k), new loads)."""
     blk, k, C = cand.shape
     cand_f = cand.reshape(blk * k, C)
@@ -50,10 +50,10 @@ def _dispatch_block(cand, gate, nc, loads, *, n_experts, w_mode):
     choice, sel, is_w, loads = route_block(
         cand_f, nc, loads, n_entities=n_experts, w_mode=w_mode
     )
-    gsel = jnp.take_along_axis(gate_f, sel[:, None], axis=-1)[:, 0]
     if w_mode:
         # spilled lanes: sel is meaningless; keep the slot's top gate
-        gsel = jnp.where(is_w, gate_f[:, 0], gsel)
+        sel = jnp.where(is_w, 0, sel)
+    gsel = pick_lane(gate_f, sel)
     return choice.reshape(blk, k), gsel.reshape(blk, k), loads
 
 
@@ -110,25 +110,24 @@ def moe_pkg_dispatch(
     return idx, gsel, loads[0]
 
 
-def _kernel_adaptive(cand_ref, gate_ref, tblk_ref, tbln_ref, idx_ref,
+def _kernel_adaptive(cand_ref, gate_ref, pref_ref, tblk_ref, tbln_ref, idx_ref,
                      gsel_ref, loads_ref, *, n_experts, d_base, d_max, w_mode):
-    blk, k, _ = cand_ref.shape
     H = tblk_ref.shape[1]
+    V = pref_ref.shape[1]
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
         loads_ref[...] = jnp.zeros_like(loads_ref)
 
-    cand = cand_ref[...]  # (blk, k, d_max)
     tk = tblk_ref[...].reshape(H)  # (H,) expert ids in this block's snapshot
     tn = tbln_ref[...].reshape(H)  # (H,) d(e) / W_SENTINEL per head expert
-    # head verdict is per TOKEN, keyed by its preferred (top-ranked) expert,
-    # then broadcast over the token's k slots
-    pref = cand[:, 0, 0]  # (blk,)
-    nc_tok = head_table_ncand(pref, tk, tn, d_base, d_max)  # (blk,)
-    nc = jnp.broadcast_to(nc_tok[:, None], (blk, k)).reshape(blk * k)
+    # head verdict is per TOKEN, keyed by its preferred (top-ranked) expert;
+    # pref_ref holds it once per slot lane, so no (blk, k) -> (blk*k,)
+    # relayout happens in the kernel
+    pref = pref_ref[...].reshape(V)  # (blk*k,)
+    nc = head_table_ncand(pref, tk, tn, d_base, d_max)
     idx, gsel, loads = _dispatch_block(
-        cand, gate_ref[...], nc, loads_ref[...],
+        cand_ref[...], gate_ref[...], nc, loads_ref[...],
         n_experts=n_experts, w_mode=w_mode,
     )
     idx_ref[...] = idx
@@ -179,8 +178,11 @@ def moe_adaptive_dispatch(
         in_specs=[
             pl.BlockSpec((block, k, cand.shape[2]), lambda i: (i, 0, 0)),
             pl.BlockSpec((block, k, cand.shape[2]), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, H), lambda i: (i, 0)),
-            pl.BlockSpec((1, H), lambda i: (i, 0)),
+            pl.BlockSpec((pl.Squeezed(), 1, block * k), lambda i: (i, 0, 0)),
+            # (T/block, 1, H) tables, one squeezed (1, H) row per step: a
+            # (1, H) block of a (T/block, H) array breaks the TPU 8-row rule
+            pl.BlockSpec((pl.Squeezed(), 1, H), lambda i: (i, 0, 0)),
+            pl.BlockSpec((pl.Squeezed(), 1, H), lambda i: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block, k), lambda i: (i, 0)),
@@ -196,7 +198,10 @@ def moe_adaptive_dispatch(
     )(
         cand.astype(jnp.int32),
         cgate,
-        tbl_keys.astype(jnp.int32),
-        tbl_ncand.astype(jnp.int32),
+        jnp.repeat(cand[:, 0, 0].astype(jnp.int32), k).reshape(
+            T // block, 1, block * k
+        ),
+        tbl_keys.astype(jnp.int32).reshape(T // block, 1, H),
+        tbl_ncand.astype(jnp.int32).reshape(T // block, 1, H),
     )
     return idx, gsel, loads[0]

@@ -28,7 +28,12 @@ from jax.experimental import pallas as pl
 
 from repro.core.hashing import derive_seeds
 from repro.kernels.platform import resolve_interpret
-from repro.kernels.route_core import hash_candidates, route_block
+from repro.kernels.route_core import (
+    chunk_loads_spec,
+    chunk_rows_spec,
+    hash_candidates,
+    route_block,
+)
 
 
 def _kernel(keys_ref, seeds_ref, *rest, n_workers, d, block, has_cap):
@@ -38,18 +43,17 @@ def _kernel(keys_ref, seeds_ref, *rest, n_workers, d, block, has_cap):
     else:
         assign_ref, loads_ref = rest
         icap = None
-    chunk = keys_ref.shape[0]
-    nblk = chunk // block
+    nblk = keys_ref.shape[0]
     seeds = seeds_ref[...]  # (d,) uint32
 
     def body(i, loads):  # loads (1, n_workers) f32
-        kb = keys_ref[pl.ds(i * block, block)]  # (V,)
+        kb = keys_ref[pl.ds(i, 1), :].reshape(block)  # (V,)
         cand = hash_candidates(kb, seeds, n_workers)  # (V, d)
         choice, _, _, loads = route_block(
             cand, None, loads, n_entities=n_workers, w_mode=False,
             inv_cap=icap,
         )
-        assign_ref[pl.ds(i * block, block)] = choice
+        assign_ref[pl.ds(i, 1), :] = choice.reshape(1, block)
         return loads
 
     loads = lax.fori_loop(0, nblk, body, jnp.zeros((1, n_workers), jnp.float32))
@@ -86,10 +90,11 @@ def pkg_route(
         _kernel, n_workers=n_workers, d=d, block=block, has_cap=has_cap
     )
     in_specs = [
-        pl.BlockSpec((chunk,), lambda i: (i,)),
+        chunk_rows_spec(chunk, block),
         pl.BlockSpec((d,), lambda i: (0,)),
     ]
-    operands = [keys.astype(jnp.int32), derive_seeds(seed, d)]
+    operands = [keys.astype(jnp.int32).reshape(N // block, block),
+                derive_seeds(seed, d)]
     if has_cap:
         icap = 1.0 / jnp.asarray(capacities, jnp.float32).reshape(1, n_workers)
         in_specs.append(pl.BlockSpec((1, n_workers), lambda i: (0, 0)))
@@ -99,13 +104,13 @@ def pkg_route(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((1, n_workers), lambda i: (i, 0)),
+            chunk_rows_spec(chunk, block),
+            chunk_loads_spec(n_workers),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((N // chunk, n_workers), jnp.float32),
+            jax.ShapeDtypeStruct((N // block, block), jnp.int32),
+            jax.ShapeDtypeStruct((N // chunk, 1, n_workers), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(*operands)
-    return assign, loads
+    return assign.reshape(N), loads.reshape(N // chunk, n_workers)

@@ -16,10 +16,13 @@ plus every matching `ref_*` oracle in kernels/ref.py and the host router
 modes in models/moe.py.  The kernel-side `route_block` speaks the TPU-native
 formulation (one-hot-matmul load fetch + histogram update, no gathers); the
 host-side `oracle_block_step` is the gather-based twin with identical mask /
-sentinel / tie-break semantics.  Both import `waterfill_picks` and
-`head_table_ncand` from here, so the W-sentinel water-fill and the head-table
-lookup cannot drift between any kernel and any oracle — the bit-exactness
-contracts in tests/test_kernels.py all reduce to this one module.
+sentinel / tie-break semantics.  Both share `_mask_and_flag` and
+`head_table_ncand`, so the masks and the head-table lookup cannot drift.  The
+W-sentinel water-fill has two formulations of one definition ("take the
+global argmin, add one"): `waterfill_assign`, sequential and Mosaic-lowerable,
+on the kernel side, and the loop-free `waterfill_picks` (top_k) in the
+oracle — so the bit-exactness contracts in tests/test_kernels.py compare
+them.
 
 Vocabulary: `n_entities` is the number of routing targets — stream workers
 for the routers, experts for MoE dispatch.  Loads are integer counts in f32
@@ -31,6 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from repro.core.estimation import W_SENTINEL
 from repro.core.hashing import splitmix32
@@ -38,8 +42,12 @@ from repro.core.hashing import splitmix32
 __all__ = [
     "LANES",
     "MASK",
+    "chunk_loads_spec",
+    "chunk_rows_spec",
     "hash_candidates",
     "waterfill_picks",
+    "waterfill_assign",
+    "pick_lane",
     "head_table_ncand",
     "route_block",
     "oracle_block_step",
@@ -52,6 +60,22 @@ MASK = 1e30
 LANES = 128  # VPU lane width the global reduction pads to
 
 
+def chunk_loads_spec(n_entities: int):
+    """Out BlockSpec of a router's per-chunk loads, an (N/chunk, 1, n) array:
+    grid step i writes row i as a (1, n) block.  The chunk axis is squeezed
+    because a (1, n) block of an (N/chunk, n) array breaks the TPU rule that
+    the second-minor block dim is a multiple of 8 or the full dim."""
+    return pl.BlockSpec((pl.Squeezed(), 1, n_entities), lambda i: (i, 0, 0))
+
+
+def chunk_rows_spec(chunk: int, block: int):
+    """BlockSpec of a per-message array laid out (N/block, block): grid step
+    i holds its chunk as chunk/block rows of one vector block each, and the
+    kernel reads block b as row b.  A flat (chunk,) block would need every
+    dynamic offset to be a multiple of 1024 on the TPU (its 1-D tiling)."""
+    return pl.BlockSpec((chunk // block, block), lambda i: (i, 0))
+
+
 def hash_candidates(kb, seeds, n_entities: int):
     """SplitMix32 candidate ids for a block of keys: (V,) x (d,) -> (V, d).
 
@@ -61,6 +85,21 @@ def hash_candidates(kb, seeds, n_entities: int):
     """
     h = splitmix32(kb.astype(jnp.uint32)[:, None] ^ seeds[None, :])  # (V, d)
     return (h % jnp.uint32(n_entities)).astype(jnp.int32)
+
+
+def _padded_row(loads, inv_cap, n_workers):
+    """(1, n_workers) loads / reciprocal-capacity rows padded to a LANES
+    multiple: pad loads hold MASK (they never win an argmin), pad
+    capacities 1.0.  Returns (row, icap or None, padded width)."""
+    pad = -n_workers % LANES
+    if not pad:
+        return loads, inv_cap, n_workers
+    row = jnp.concatenate([loads, jnp.full((1, pad), MASK, jnp.float32)], 1)
+    if inv_cap is not None:
+        inv_cap = jnp.concatenate(
+            [inv_cap, jnp.ones((1, pad), jnp.float32)], 1
+        )
+    return row, inv_cap, n_workers + pad
 
 
 def waterfill_picks(loads, *, n_workers, block, inv_cap=None):
@@ -79,35 +118,25 @@ def waterfill_picks(loads, *, n_workers, block, inv_cap=None):
     negated values (top_k surfaces the lowest flat index first on ties, so
     ties land on the lowest worker, then ascending t, matching argmin's
     first-index rule at every step).  Loads are integer counts in f32, so
-    values and ties are IEEE-exact; every oracle imports this function so
-    kernel and oracle cannot drift.
+    values and ties are IEEE-exact.  The oracle uses this formulation; the
+    kernels use `waterfill_assign`, which Mosaic can lower.
 
     With `inv_cap` (a (1, n_workers) reciprocal-capacity row, arXiv
     1705.09073) the argmin runs over capacity-normalized values
     ``(L_j + t) / c_j`` — computed as ``(L_j + t) * inv_cap_j``, the SAME
     float product the sequential host scan forms, so block=1 stays
-    bit-exact to the host and any block stays exact vs the oracle (shared
-    code).  The multiset argument is unchanged: values still increase
+    bit-exact to the host and any block stays exact vs the kernels' loop.
+    The multiset argument is unchanged: values still increase
     strictly in t for every worker (inv_cap > 0).  A uniform inv_cap of
     1.0 multiplies exactly and reproduces the unweighted picks bit-for-bit.
 
     Returns picks (block,) int32 worker ids.
     """
-    pad = -n_workers % LANES
-    row = loads
-    icap = inv_cap
-    if pad:
-        row = jnp.concatenate(
-            [row, jnp.full((1, pad), MASK, jnp.float32)], axis=1
-        )
-        if icap is not None:
-            icap = jnp.concatenate(
-                [icap, jnp.ones((1, pad), jnp.float32)], axis=1
-            )
+    row, icap, w_pad = _padded_row(loads, inv_cap, n_workers)
     t = jnp.arange(block, dtype=jnp.float32)
-    vals = row.reshape(n_workers + pad, 1) + t[None, :]  # (W_pad, B): (j, t)
+    vals = row.reshape(w_pad, 1) + t[None, :]  # (W_pad, B): (j, t)
     if icap is not None:
-        vals = vals * icap.reshape(n_workers + pad, 1)
+        vals = vals * icap.reshape(w_pad, 1)
     _, idx = lax.top_k(-vals.reshape(-1), block)  # ties -> j-major
     return (idx // block).astype(jnp.int32)
 
@@ -136,13 +165,63 @@ def _mask_and_flag(lc, nc, d_max: int, w_mode: bool):
     return jnp.where(col[None, :] < nc_tail[:, None], lc, jnp.float32(MASK)), is_w
 
 
+def pick_lane(x, sel):
+    """x[i, sel[i]] for (V, d) x and (V,) sel, as a masked select over the d
+    lanes: Mosaic has no gather, and one live lane plus zeros sums exactly."""
+    col = jnp.arange(x.shape[-1], dtype=jnp.int32)
+    return jnp.sum(jnp.where(col[None, :] == sel[:, None], x, 0), axis=-1)
+
+
+def waterfill_assign(loads, is_w, *, n_workers, inv_cap=None):
+    """Water-fill destinations of a block's head lanes, computed by the
+    definition itself: for each head lane in lane order, take the masked
+    global argmin of the running loads row and add one to it.
+
+    loads (1, n_workers) f32 block-start row, is_w (V,) bool head flags,
+    inv_cap optional (1, n_workers) reciprocal capacities.  Returns (V,)
+    int32; lanes that are not heads read 0.  The row is padded to a LANES
+    multiple with the MASK sentinel (pad lanes never win), ties break to
+    the lowest worker index, and the capacity-normalized value is the same
+    ``(L_j + t) * inv_cap_j`` product the host scan forms.  The loop runs V
+    steps of lane reductions and selects only, so it compiles under Mosaic
+    (no top_k, sort, cumsum or gather); `waterfill_picks` is the loop-free
+    formulation of the same sequence that the host oracles use.
+    """
+    V = is_w.shape[0]
+    row, icap, w_pad = _padded_row(loads, inv_cap, n_workers)
+    wid = lax.broadcasted_iota(jnp.int32, (1, w_pad), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (1, V), 1)
+
+    def body(_, c):
+        cnt, todo, out = c  # (1, w_pad) f32, (1, V) i32, (1, V) i32
+        vals = row + cnt
+        if icap is not None:
+            vals = vals * icap
+        m = jnp.min(vals, axis=1, keepdims=True)
+        j = jnp.min(jnp.where(vals == m, wid, w_pad), axis=1, keepdims=True)
+        first = jnp.min(jnp.where(todo > 0, lane, V), axis=1, keepdims=True)
+        hit = lane == first  # the lowest head lane still unassigned
+        return (
+            cnt + (wid == j).astype(jnp.float32),
+            jnp.where(hit, 0, todo),
+            jnp.where(hit, j, out),
+        )
+
+    init = (
+        jnp.zeros((1, w_pad), jnp.float32),
+        is_w.astype(jnp.int32).reshape(1, V),
+        jnp.zeros((1, V), jnp.int32),
+    )
+    return lax.fori_loop(0, V, body, init)[2].reshape(V)
+
+
 def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
     """The kernel-side masked-greedy routing core for one vector block.
 
     cand (V, d_max) int32 candidate entity ids, nc (V,) int32 candidate
     counts (or None: all d_max lanes live), loads (1, n_entities) f32.
     Returns (choice (V,), sel (V,), is_w (V,) or None, new loads).  `sel` is
-    the winning candidate column (MoE dispatch gathers the matching gate with
+    the winning candidate column (MoE dispatch selects the matching gate with
     it); `is_w` flags the lanes the W path overrode (their `sel` is
     meaningless).  Every Pallas router calls this — the callers differ ONLY
     in how cand/nc are produced — so sentinel/tie-break/update semantics
@@ -162,42 +241,36 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
 
     With w_mode (static), lanes with nc == W_SENTINEL take the W-Choices
     path: the r-th such lane of the block gets the r-th water-fill argmin of
-    the block-start loads row (waterfill_picks), so consecutive head
+    the block-start loads row (waterfill_assign), so consecutive head
     messages spread exactly as the sequential global-argmin would.  Tail
     lanes still read block-start loads only — the same < block staleness
     contract as the load vector itself (DESIGN.md SS2).  w_mode=False skips
-    the reduction entirely for callers that never emit the sentinel;
+    the water-fill entirely for callers that never emit the sentinel;
     sentinel-free streams route identically either way.
     """
     V, d_max = cand.shape
     eid = jnp.arange(n_entities, dtype=jnp.int32)
     onehot_c = (cand[..., None] == eid).astype(jnp.float32)  # (V, d_max, n)
     row = loads if inv_cap is None else loads * inv_cap
+    # HIGHEST: a TPU f32 matmul otherwise runs one bf16 pass, which rounds
+    # any load above 256 (8 mantissa bits); full f32 fetches it exactly
     lc = jax.lax.dot_general(
         onehot_c.reshape(V * d_max, n_entities),
         row.reshape(n_entities, 1),
         (((1,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).reshape(V, d_max)
     lc, is_w = _mask_and_flag(lc, nc, d_max, w_mode)
-    sel = jnp.argmin(lc, axis=-1)  # (V,) ties -> first candidate
-    choice = jnp.take_along_axis(cand, sel[:, None], axis=-1)[:, 0]
+    # argmin with ties -> first candidate, as min + lowest matching column
+    col = lax.broadcasted_iota(jnp.int32, (V, d_max), 1)
+    m = jnp.min(lc, axis=-1, keepdims=True)
+    sel = jnp.min(jnp.where(lc == m, col, d_max), axis=-1)
+    choice = pick_lane(cand, sel)
     if w_mode:
-        # W path: head rank within the block -> water-fill pick, fetched with
-        # a one-hot matmul (gather-free, DESIGN.md SS7; picks < n_entities
-        # are f32-exact).  rank < V always: at most V head lanes precede.
-        rank = jnp.cumsum(is_w.astype(jnp.int32)) - is_w  # (V,)
-        picks = waterfill_picks(
-            loads, n_workers=n_entities, block=V, inv_cap=inv_cap
+        head_choice = waterfill_assign(
+            loads, is_w, n_workers=n_entities, inv_cap=inv_cap
         )
-        lane = jnp.arange(V, dtype=jnp.int32)
-        onehot_r = (rank[:, None] == lane[None, :]).astype(jnp.float32)  # (V, V)
-        head_choice = jax.lax.dot_general(
-            onehot_r,
-            picks.astype(jnp.float32).reshape(V, 1),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).reshape(V).astype(jnp.int32)
         choice = jnp.where(is_w, head_choice, choice)
     hist = (choice[:, None] == eid).astype(jnp.float32).sum(axis=0)
     return choice, sel, is_w, loads + hist[None, :]
@@ -214,7 +287,8 @@ def oracle_block_step(loads, cand, nc, *, n_entities, w_mode, inv_cap=None):
     read — deliberately a DIFFERENT formulation from the kernel's one-hot
     matmuls, so the differential tests check the MXU formulation against
     straightforward indexing while the mask/sentinel/tie-break logic stays
-    shared (same _mask_and_flag, same waterfill_picks)."""
+    shared (same _mask_and_flag).  The W picks come from the loop-free
+    waterfill_picks, the kernel's sequential waterfill_assign's twin."""
     d_max = cand.shape[-1]
     row = loads if inv_cap is None else loads * inv_cap
     lc = row[cand]  # (V, d_max)

@@ -41,7 +41,14 @@ from repro.parallel.sharding import (  # noqa: E402
     param_shardings,
 )
 from repro.parallel.spec import abstract  # noqa: E402
-from repro.roofline.analysis import HW, collective_bytes, model_flops, roofline_report  # noqa: E402
+from repro.roofline.analysis import (  # noqa: E402
+    HW,
+    V5E,
+    collective_bytes,
+    hw_for,
+    model_flops,
+    roofline_report,
+)
 from repro.train.loop import make_train_step  # noqa: E402
 
 V5E_HBM = 16 * 1024**3
@@ -157,7 +164,8 @@ def calibrated_costs(cfg, shape, mesh, force_big: bool) -> dict:
     return out
 
 
-def run_cell(arch: str, shape_name: str, multi_pod: bool, hw: HW = HW()) -> dict:
+def run_cell(arch: str, shape_name: str, multi_pod: bool,
+             hw: HW = hw_for(V5E)) -> dict:
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)

@@ -71,6 +71,7 @@ def main() -> None:
 
     from repro.configs import get_config, make_tiny
     from repro.core.routing import make_policy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core.streams import multi_tenant_stream
     from repro.models import init_params
     from repro.serving import (
@@ -80,6 +81,7 @@ def main() -> None:
         simulate_serving,
     )
 
+    enable_compile_cache()
     capacities = None
     if args.capacities is not None:
         pat = np.asarray([float(c) for c in args.capacities.split(",")])

@@ -47,10 +47,12 @@ def main() -> None:
     from repro.checkpoint import CheckpointManager
     from repro.configs import TrainConfig, get_config, make_tiny
     from repro.data import PKGDataPipeline, SyntheticCorpus
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.optim import adamw_init
     from repro.train import TrainingHarness, make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.tiny:
         cfg = make_tiny(cfg)

@@ -67,18 +67,17 @@ def make_pipelined_fn(stage_fn: Callable, mesh, n_stages: int, axis_name: str = 
     stacked_params: leading dim n_stages on every leaf (stage s's slice lives
     on stage s); x_microbatches (M, mb, ...) replicated.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def inner(stacked_params, x_mb):
         my = jax.tree_util.tree_map(lambda a: a[0], stacked_params)
         return pipeline_forward(stage_fn, my, x_mb, axis_name)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)

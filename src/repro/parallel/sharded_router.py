@@ -41,7 +41,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 
 from repro.core.estimation import W_SENTINEL
 from repro.core.hashing import derive_seeds
@@ -139,9 +138,9 @@ def _build_sharded(n_workers, d_max, n_shards, n_epochs, sync_period, block,
     in_specs, out_specs = stream_shard_specs(
         has_ncand=has_nc, has_cap=has_cap, has_weights=has_w
     )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -348,15 +347,17 @@ def routed_step_roofline(
     per-epoch collective bytes (the psum traffic), and the three-term
     roofline report.  Collective bytes are parsed from the post-SPMD HLO,
     so on a 1-shard mesh they are exactly zero — the sync is free when
-    there is nobody to sync with.
+    there is nobody to sync with.  ``hw`` (a roofline.analysis.HW) defaults
+    to the peaks of the mesh's device kind; a kind without published peaks
+    raises, so a CPU compile must name the chip it models.
     """
-    from repro.roofline.analysis import HW, collective_bytes, roofline_report
+    from repro.roofline.analysis import collective_bytes, hw_for, roofline_report
 
-    hw = hw or HW()
     if mesh is None:
         from repro.launch.mesh import make_stream_mesh
 
         mesh = make_stream_mesh(n_shards)
+    hw = hw or hw_for(mesh.devices.flat[0].device_kind)
     N = n_shards * n_epochs * sync_period * block
     fn = _build_sharded(
         n_workers, d_max, n_shards, n_epochs, sync_period, block,

@@ -1,15 +1,17 @@
-"""Three-term roofline model from compiled dry-run artifacts (TPU v5e target).
+"""Three-term roofline model from compiled artifacts, per device kind.
 
-  compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF/s bf16)
-  memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-  collective = collective_bytes_per_device / link_bw      (~50 GB/s/link ICI)
+  compute    = HLO_FLOPs_per_device / peak_FLOPs
+  memory     = HLO_bytes_per_device / HBM_bw
+  collective = collective_bytes_per_device / link_bw
 
-`compiled.cost_analysis()` reports the per-device (post-SPMD) module, so its
-flops/bytes are already per-chip.  Collective bytes are NOT in cost_analysis:
-we parse the post-partitioning HLO and sum the (per-device) result-shape
-bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute.  Cross-pod collectives (replica_groups spanning pods)
-ride DCN; we report them separately with a 25 GB/s assumption.
+The peaks come from PEAKS, keyed by the device's `device_kind`; a kind that
+is not in the table is an error, never a default.  `compiled.cost_analysis()`
+reports the per-device (post-SPMD) module, so its flops/bytes are already
+per-chip.  Collective bytes are NOT in cost_analysis: we parse the
+post-partitioning HLO and sum the (per-device) result-shape bytes of every
+all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
+Cross-pod collectives (replica_groups spanning pods) ride DCN; we report
+them separately with a 25 GB/s assumption.
 """
 from __future__ import annotations
 
@@ -17,15 +19,38 @@ import dataclasses
 import re
 from typing import Optional
 
-__all__ = ["HW", "collective_bytes", "roofline_report", "model_flops"]
+__all__ = [
+    "HW", "PEAKS", "V5E", "hw_for", "collective_bytes", "roofline_report",
+    "model_flops",
+]
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9  # bytes/s
-    ici_bw: float = 50e9  # bytes/s per link
-    dcn_bw: float = 25e9  # bytes/s cross-pod
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per link
+    dcn_bw: float = 25e9  # bytes/s cross-pod (an assumption, not a chip peak)
+
+
+V5E = "TPU v5 lite"  # device_kind JAX reports for a TPU v5e chip
+
+# Published per-chip peaks, keyed by device_kind.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of interchip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    V5E: HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def hw_for(device_kind: str) -> HW:
+    """Peaks of one device kind; an unknown kind raises ValueError."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add them to roofline.analysis.PEAKS"
+        )
+    return PEAKS[device_kind]
 
 
 _DTYPE_BYTES = {
@@ -100,7 +125,7 @@ def roofline_report(
     flops_per_device: float,
     hbm_bytes_per_device: float,
     coll_bytes_per_device: float,
-    hw: HW = HW(),
+    hw: HW,
     dcn_bytes_per_device: float = 0.0,
 ) -> dict:
     t_comp = flops_per_device / hw.peak_flops

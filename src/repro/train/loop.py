@@ -118,7 +118,6 @@ def make_dp_train_step(cfg, tcfg, mesh, dp_axis: str = "pod"):
     (parallel.compression).  opt_state gains an "ef" residual tree.
     Returns (train_step, init_fn) where init_fn wraps adamw_init.
     """
-    from jax.experimental.shard_map import shard_map
 
     lr_fn = make_schedule(
         tcfg.schedule, tcfg.learning_rate, tcfg.warmup_steps, tcfg.total_steps
@@ -146,11 +145,11 @@ def make_dp_train_step(cfg, tcfg, mesh, dp_axis: str = "pod"):
         loss = lax.pmean(loss, dp_axis)
         return params, st, {"loss": loss, "gnorm": gnorm, "lr": lr}
 
-    step_fn = shard_map(
+    step_fn = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(P(), P(), P(dp_axis), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(step_fn), init_fn

@@ -8,7 +8,6 @@ from repro.core.estimation import W_SENTINEL, online_head_tables
 from repro.core.streams import drift_stream, zipf_stream
 from repro.kernels import ref
 from repro.kernels.adaptive_route import (
-    _waterfill_picks,
     adaptive_route,
     adaptive_route_online,
     w_route,
@@ -17,6 +16,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch, moe_pkg_dispatch
 from repro.kernels.pkg_route import pkg_route
 from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.route_core import waterfill_assign, waterfill_picks
 from repro.models.moe import _pkg_choose, expert_head_tables
 
 
@@ -114,6 +114,18 @@ def test_adaptive_route_all_two_choices_is_pkg_route():
 # ---------------------------------------------------------------------------
 
 
+def _sequential_waterfill(loads, n, inv_cap=None):
+    """The definition: n times, take the (capacity-normalized) argmin of
+    the running loads and add one there."""
+    icap = np.ones_like(loads) if inv_cap is None else inv_cap
+    sim, cur = [], loads.copy()
+    for _ in range(n):
+        j = int(np.argmin(cur * icap))
+        sim.append(j)
+        cur[j] += 1.0
+    return sim
+
+
 @pytest.mark.parametrize("n_workers", [7, 100, 150, 200])
 def test_waterfill_picks_equal_sequential_argmin(n_workers):
     """The loop-free water-fill must reproduce 'argmin, add one' exactly —
@@ -122,14 +134,37 @@ def test_waterfill_picks_equal_sequential_argmin(n_workers):
     rng = np.random.default_rng(n_workers)
     loads = rng.integers(0, 40, n_workers).astype(np.float32)
     picks = np.asarray(
-        _waterfill_picks(jnp.asarray(loads)[None, :], n_workers=n_workers, block=96)
+        waterfill_picks(jnp.asarray(loads)[None, :], n_workers=n_workers, block=96)
     )
-    sim, cur = [], loads.copy()
-    for _ in range(96):
-        j = int(np.argmin(cur))
-        sim.append(j)
-        cur[j] += 1.0
-    assert picks.tolist() == sim
+    assert picks.tolist() == _sequential_waterfill(loads, 96)
+
+
+@pytest.mark.parametrize("n_workers", [7, 100, 150, 200])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_waterfill_assign_equals_sequential_argmin(n_workers, weighted):
+    """The kernels' sequential water-fill gives the r-th head lane of a
+    block the r-th pick of the definition — head lanes scattered over the
+    block, ties to the lowest worker, capacity-normalized values included —
+    and equals the oracle's loop-free picks."""
+    rng = np.random.default_rng(n_workers + weighted)
+    loads = rng.integers(0, 40, n_workers).astype(np.float32)
+    icap = (
+        (1.0 / rng.integers(1, 5, n_workers)).astype(np.float32)
+        if weighted else None
+    )
+    is_w = rng.random(128) < 0.4
+    icap_row = None if icap is None else jnp.asarray(icap)[None, :]
+    got = np.asarray(waterfill_assign(
+        jnp.asarray(loads)[None, :], jnp.asarray(is_w), n_workers=n_workers,
+        inv_cap=icap_row,
+    ))
+    sim = _sequential_waterfill(loads, int(is_w.sum()), icap)
+    assert got[is_w].tolist() == sim
+    picks = np.asarray(waterfill_picks(
+        jnp.asarray(loads)[None, :], n_workers=n_workers, block=128,
+        inv_cap=icap_row,
+    ))
+    assert got[is_w].tolist() == picks[: int(is_w.sum())].tolist()
 
 
 @pytest.mark.parametrize("n_workers", [7, 50, 100, 200])
@@ -227,7 +262,7 @@ def test_w_choices_kernel_partition_registered_and_bit_exact_at_block1():
 
 @pytest.mark.parametrize("n_workers", [50, 100])
 def test_adaptive_route_online_any_worker_matches_ref(n_workers):
-    """Online W-Choices: sentinel head tables flow through _head_table_ncand
+    """Online W-Choices: sentinel head tables flow through head_table_ncand
     unclipped and the kernel matches ref_w_route_online bit-exactly."""
     keys = jnp.asarray(drift_stream(4096, 800, 1.8, half_life=2048, seed=2))
     tk, tn = online_head_tables(

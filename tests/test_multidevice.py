@@ -27,6 +27,7 @@ def _run(code: str):
 def test_sharded_train_step_runs_and_matches_single_device():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config, make_tiny, TrainConfig
     from repro.models import init_params
@@ -46,7 +47,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     step0 = jax.jit(make_train_step(cfg, tcfg))
     p_ref, _, m_ref = step0(params, opt, batch, jnp.int32(0))
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     plan = make_plan(cfg, mesh)
     sh = make_sharder(cfg, mesh, plan, "train", 8)
     pspecs = param_shardings(cfg, mesh, plan)
@@ -71,6 +72,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
 def test_compressed_dp_step_close_to_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.configs import get_config, make_tiny, TrainConfig
     from repro.models import init_params
     from repro.optim import adamw_init
@@ -84,7 +86,7 @@ def test_compressed_dp_step_close_to_exact():
     batch = {"tokens": jax.random.randint(key, (8, 64), 0, cfg.vocab_size),
              "labels": jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, cfg.vocab_size)}
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
     step, init_fn = make_dp_train_step(cfg, tcfg, mesh, dp_axis="pod")
     opt = init_fn(params)
     p1, o1, m1 = step(params, opt, batch, jnp.int32(0))
@@ -104,11 +106,12 @@ def test_compressed_dp_step_close_to_exact():
 def test_decode_cell_builder_smoke():
     _run("""
     import jax
+    from jax.sharding import AxisType
     from repro.configs import get_config, make_tiny
     from repro.configs.base import ShapeConfig
     from repro.launch.dryrun import build_cell
     cfg = make_tiny(get_config("gemma3-4b"))
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
     for shape in [ShapeConfig("train", "train", 64, 8), ShapeConfig("decode", "decode", 128, 8)]:
         fn, args = build_cell(cfg, shape, mesh)
         fn.lower(*args).compile()
@@ -120,6 +123,7 @@ def test_decode_cell_builder_smoke():
 def test_pipeline_parallel_parity():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from jax.sharding import PartitionSpec as P
     from repro.parallel.pipeline import make_pipelined_fn
 
@@ -131,7 +135,7 @@ def test_pipeline_parallel_parity():
         return jnp.tanh(x @ w)
 
     x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, D))
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
     piped = make_pipelined_fn(stage_fn, mesh, S)
     out_p = piped(ws, x)
 

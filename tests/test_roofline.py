@@ -1,10 +1,14 @@
 """Roofline machinery: HLO collective parser, terms, model flops."""
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.roofline.analysis import (
     HW,
+    PEAKS,
+    V5E,
     collective_bytes,
+    hw_for,
     model_flops,
     roofline_report,
 )
@@ -71,3 +75,10 @@ def test_param_counts_order_of_magnitude():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo < n < hi, (arch, n)
+
+
+def test_peaks_keyed_by_device_kind():
+    assert hw_for(V5E) is PEAKS[V5E]
+    assert hw_for(V5E).peak_flops == 197e12 and hw_for(V5E).hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        hw_for("cpu")

@@ -39,6 +39,7 @@ from repro.parallel.sharded_router import (
     routed_step_roofline,
     sharded_route,
 )
+from repro.roofline.analysis import V5E, hw_for
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 W = 16
@@ -192,13 +193,20 @@ def test_make_stream_mesh_rejects_oversubscription():
 
 
 def test_routed_step_roofline_report():
-    rep = routed_step_roofline(W, n_shards=1, sync_period=4, n_epochs=2)
+    rep = routed_step_roofline(W, n_shards=1, sync_period=4, n_epochs=2,
+                               hw=hw_for(V5E))
     assert rep["flops_per_device"] > 0 and rep["hbm_bytes_per_device"] > 0
     assert rep["roofline"]["step_lower_bound_s"] > 0
     assert rep["collective_bytes_per_epoch"] >= 0
     assert rep["collective_bytes_per_device"] == (
         rep["collective_bytes_per_epoch"] * rep["n_epochs"]
     )
+
+
+def test_routed_step_roofline_needs_known_device_kind():
+    # the host CPU has no published peaks: no silent v5e default
+    with pytest.raises(ValueError, match="no published peaks"):
+        routed_step_roofline(W, n_shards=1, sync_period=4, n_epochs=2)
 
 
 # ---------------------------------------------------------------------------
