@@ -31,7 +31,8 @@ Each phase prints one JSON line.  The last line is
 {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}} and is
 printed only when every check passed.  The script exits non-zero, without
 that line, on a failed check or when JAX finds no TPU.  Every phase runs in
-this one process, which holds the chip(s).
+this one process, which holds the chip(s).  It times nothing: speed is the
+benchmark's (BENCHMARK.json, benchmarks/chip/run_cell.py).
 """
 from __future__ import annotations
 
@@ -39,7 +40,6 @@ import argparse
 import itertools
 import json
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -95,20 +95,6 @@ def first_diff(a: np.ndarray, b: np.ndarray):
     return int(bad[0]) if len(bad) else None
 
 
-def timed(fn):
-    t0 = time.perf_counter()
-    out = fn()
-    return out, time.perf_counter() - t0
-
-
-def compile_step(policy: str, seed: int) -> float:
-    """Seconds to compile a policy's chunk step and run one chunk, on a
-    throwaway router: every router of one configuration shares the compiled
-    step, so this is near 0 once an earlier phase compiled it."""
-    warm = make_router(policy, seed)
-    return timed(lambda: warm.route_stream(np.zeros(CHUNK, np.int32)))[1]
-
-
 # -- one chip -----------------------------------------------------------------
 
 
@@ -127,20 +113,17 @@ def prefix_checks(n: int, seed: int, dev) -> None:
     keys_cpu = jax.device_put(keys, cpu)
     keys_dev = jax.device_put(keys, dev)
     for policy in POLICIES:
-        compile_sec = compile_step(policy, seed)
         router = make_router(policy, seed)
-        chunked, sec = timed(lambda: router.route_stream(keys))
+        chunked = router.route_stream(keys)
         w_mode = policy == "w_choices"
         if policy == "pkg":
             oracle = ref.ref_pkg_route(
                 keys_cpu, W, d=2, seed=seed, chunk=n, block=BLOCK
             )[0]
-
-            def kernel_call():
-                return jax.block_until_ready(pkg_route(
-                    keys_dev, W, d=2, seed=seed, chunk=n, block=BLOCK,
-                    interpret=False,
-                )[0])
+            kernel = pkg_route(
+                keys_dev, W, d=2, seed=seed, chunk=n, block=BLOCK,
+                interpret=False,
+            )[0]
         else:
             tk, tn = online_head_tables(
                 keys_cpu, BLOCK, SS_CAPACITY, W, d=router.d,
@@ -152,15 +135,11 @@ def prefix_checks(n: int, seed: int, dev) -> None:
                 seed=seed, chunk=n, block=BLOCK, w_mode=w_mode,
             )[0]
             tk_dev, tn_dev = jax.device_put((tk, tn), dev)
-
-            def kernel_call():
-                return jax.block_until_ready(adaptive_route_online(
-                    keys_dev, tk_dev, tn_dev, W, d_base=router.d,
-                    d_max=router.d_max, seed=seed, chunk=n, block=BLOCK,
-                    interpret=False, w_mode=w_mode,
-                )[0])
-        _, kernel_first_sec = timed(kernel_call)  # compiles
-        kernel, kernel_sec = timed(kernel_call)
+            kernel = adaptive_route_online(
+                keys_dev, tk_dev, tn_dev, W, d_base=router.d,
+                d_max=router.d_max, seed=seed, chunk=n, block=BLOCK,
+                interpret=False, w_mode=w_mode,
+            )[0]
         kernel_name = "pkg_route" if policy == "pkg" else (
             f"adaptive_route_online(w_mode={w_mode})"
         )
@@ -168,10 +147,7 @@ def prefix_checks(n: int, seed: int, dev) -> None:
         chunked_eq_ref = bool(np.array_equal(chunked, oracle))
         pallas_eq_chunked = bool(np.array_equal(kernel, chunked))
         emit(
-            phase=f"prefix/{policy}", events=n, chunked_seconds=sec,
-            compile_seconds=compile_sec, pallas_kernel=kernel_name,
-            pallas_seconds=kernel_sec,
-            pallas_first_call_seconds=kernel_first_sec,
+            phase=f"prefix/{policy}", events=n, pallas_kernel=kernel_name,
             chunked_eq_ref=chunked_eq_ref,
             pallas_eq_chunked=pallas_eq_chunked,
             first_diff_chunked_ref=first_diff(chunked, oracle),
@@ -185,7 +161,6 @@ def stream_phase(policy: str, events: int, seed: int) -> None:
     """WP chunks -> ChunkedRouter.route_stream -> histogram sink."""
     from repro.core.streams import PAPER_DATASETS
 
-    compile_sec = compile_step(policy, seed)
     router = make_router(policy, seed)
     hist = np.zeros(W, np.int64)
     bad = [0]
@@ -196,12 +171,11 @@ def stream_phase(policy: str, events: int, seed: int) -> None:
 
     chunks = PAPER_DATASETS["WP"].stream_chunks(CHUNK, seed=seed)
     chunks = itertools.islice(chunks, -(-events // CHUNK))
-    n, sec = timed(lambda: router.route_stream(chunks, on_chunk=sink))
+    n = router.route_stream(chunks, on_chunk=sink)
     full = PAPER_DATASETS["WP"].n_msgs
     emit(
         phase=f"stream/{policy}", events=n, stream_events=full,
         cut=None if n >= full else f"first {n} of {full} events",
-        seconds=sec, compile_seconds=compile_sec, events_per_sec=n / sec,
         final_imbalance=float(hist.max() - hist.mean()) / n,
         max_load=int(hist.max()), min_load=int(hist.min()),
         hist_sum=int(hist.sum()), out_of_range=bad[0],
@@ -219,7 +193,6 @@ def stream_phase(policy: str, events: int, seed: int) -> None:
 
 
 def four_chip_phase(seed: int) -> None:
-    import jax
     import jax.numpy as jnp
 
     from repro.core.estimation import (
@@ -250,12 +223,8 @@ def four_chip_phase(seed: int) -> None:
             nc = nc_j if w_mode else None
             kw = dict(d_max=2, seed=seed, n_shards=4, sync_period=sync,
                       block=BLOCK, w_mode=w_mode)
-            (a_s, l_s), sec = timed(lambda: jax.block_until_ready(
-                sharded_route(keys_j, nc, W, mesh=mesh, **kw)
-            ))
-            (a_r, l_r), rsec = timed(lambda: jax.block_until_ready(
-                ref_sharded_route(keys_j, nc, W, **kw)
-            ))
+            a_s, l_s = sharded_route(keys_j, nc, W, mesh=mesh, **kw)
+            a_r, l_r = ref_sharded_route(keys_j, nc, W, **kw)
             shard_devices = {s.device.id for s in a_s.addressable_shards}
             a_s_np = np.asarray(a_s)
             hist = np.bincount(a_s_np, minlength=W)
@@ -266,8 +235,7 @@ def four_chip_phase(seed: int) -> None:
             name = "w_choices" if w_mode else "pkg"
             emit(
                 phase=f"four_chip/{name}/sync{sync}", events=len(keys),
-                n_heads=len(head_ids), sharded_first_call_seconds=sec,
-                ref_first_call_seconds=rsec, shard_devices=sorted(shard_devices),
+                n_heads=len(head_ids), shard_devices=sorted(shard_devices),
                 final_imbalance=float(hist.max() - hist.mean()) / len(keys),
                 sharded_eq_ref=bit_exact,
             )
@@ -329,7 +297,6 @@ def main() -> int:
          devices=len(jax.devices()), used=n_used, jax=jax.__version__,
          compile_cache=cache_dir)
 
-    t0 = time.perf_counter()
     try:
         if args.four_chips:
             four_chip_phase(args.seed)
@@ -341,7 +308,6 @@ def main() -> int:
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    emit(phase="done", seconds=time.perf_counter() - t0)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": n_used,
     }}), flush=True)

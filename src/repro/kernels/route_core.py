@@ -268,9 +268,10 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
     sel = jnp.min(jnp.where(lc == m, col, d_max), axis=-1)
     choice = pick_lane(cand, sel)
     if w_mode:
-        head_choice = waterfill_assign(
-            loads, is_w, n_workers=n_entities, inv_cap=inv_cap
-        )
+        with jax.named_scope("waterfill"):
+            head_choice = waterfill_assign(
+                loads, is_w, n_workers=n_entities, inv_cap=inv_cap
+            )
         choice = jnp.where(is_w, head_choice, choice)
     hist = (choice[:, None] == eid).astype(jnp.float32).sum(axis=0)
     return choice, sel, is_w, loads + hist[None, :]

@@ -42,6 +42,18 @@ block keys), routed by the same vmap-of-``_block_scan``-plus-summed-deltas
 program as ``ref_sharded_route``, with the global loads row carried across
 chunks — chunk boundaries ARE the load-sync boundaries.
 
+Profiler spans (``jax.profiler.TraceAnnotation``, on the device trace's
+clock; without an active profiler session each costs only its
+constructor).  ``ChunkedRouter.route_stream`` opens ``router.stream`` per
+call and, per chunk, ``router.put`` (rebuffer copy + ``device_put``),
+``router.dispatch`` (enqueueing the step) and ``router.pull`` (trim, wait,
+device->host copy); the last two carry ``chunk=<n>`` from
+``ChunkedRouter.n_chunks``.  None of them encloses the caller's iterator or
+``on_chunk``.  Inside the step, ``jax.named_scope`` marks ``ss_head_table``
+and ``ss_update`` (adaptive policies) and ``waterfill`` (W-Choices, in
+``route_core.route_block``); the step's program keeps jit's name,
+``jit_step``.
+
 Import directly (``from repro.parallel.chunked_driver import ChunkedRouter``);
 like parallel.sharding this module is not re-exported from repro.parallel.
 """
@@ -54,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core.estimation import (
     OnlineSS,
@@ -151,11 +164,12 @@ def _build_step(cfg: _StepConfig) -> Callable:
             if adaptive:
                 # Table emitted from the state BEFORE this block (stale by
                 # <= block messages) — online_head_tables' exact emit.
-                tk, tn = online_ss_head_table(
-                    state, cfg.n_workers, d=cfg.d, d_max=cfg.d_max,
-                    theta=cfg.theta, slack=cfg.slack,
-                    min_count=cfg.min_count, any_worker=w_mode,
-                )
+                with jax.named_scope("ss_head_table"):
+                    tk, tn = online_ss_head_table(
+                        state, cfg.n_workers, d=cfg.d, d_max=cfg.d_max,
+                        theta=cfg.theta, slack=cfg.slack,
+                        min_count=cfg.min_count, any_worker=w_mode,
+                    )
                 nc = head_table_ncand(kb, tk, tn, cfg.d, cfg.d_max)
                 nc = jnp.where(vb > 0, nc, jnp.int32(cfg.d))
             else:
@@ -185,7 +199,8 @@ def _build_step(cfg: _StepConfig) -> Callable:
                         lambda s: s, s,
                     ), None
 
-                state = lax.scan(upd, state, (kb, vb))[0]
+                with jax.named_scope("ss_update"):
+                    state = lax.scan(upd, state, (kb, vb))[0]
             return (loads, state, b + jnp.int32(1)), choice
 
         carry, choices = lax.scan(blk, carry, (kb_all, vb_all))
@@ -279,6 +294,7 @@ class ChunkedRouter:
         self._valid_full = jax.device_put(np.ones(self.chunk, np.int32))
         self._killed: dict[int, float] = {}
         self.n_routed = 0
+        self.n_chunks = 0  # chunk steps dispatched; the spans' `chunk` id
 
     # -- observability ------------------------------------------------------
 
@@ -332,26 +348,34 @@ class ChunkedRouter:
         """Rebuffer arbitrary-size chunks into exact `chunk`-size pieces and
         device_put them (async — overlaps the in-flight step's compute).
         Only the final piece may be partial; it ships zero-padded with a
-        valid mask."""
+        valid mask.  Each copy (and the device_put it completes) is one
+        `router.put` span, closed before the next piece is pulled from
+        `chunks` or handed to the caller."""
         buf = np.empty(self.chunk, np.int32)
         fill = 0
         for arr in chunks:
             arr = np.asarray(arr, np.int32).reshape(-1)
             off = 0
             while off < len(arr):
-                n = min(len(arr) - off, self.chunk - fill)
-                buf[fill : fill + n] = arr[off : off + n]
-                fill += n
-                off += n
-                if fill == self.chunk:
-                    yield jax.device_put(buf.copy()), self._valid_full, fill
-                    fill = 0
+                piece = None
+                with TraceAnnotation("router.put"):
+                    n = min(len(arr) - off, self.chunk - fill)
+                    buf[fill : fill + n] = arr[off : off + n]
+                    fill += n
+                    off += n
+                    if fill == self.chunk:
+                        piece = jax.device_put(buf.copy()), self._valid_full, fill
+                        fill = 0
+                if piece is not None:
+                    yield piece
         if fill:
-            keys = np.zeros(self.chunk, np.int32)
-            keys[:fill] = buf[:fill]
-            valid = np.zeros(self.chunk, np.int32)
-            valid[:fill] = 1
-            yield jax.device_put(keys), jax.device_put(valid), fill
+            with TraceAnnotation("router.put"):
+                keys = np.zeros(self.chunk, np.int32)
+                keys[:fill] = buf[:fill]
+                valid = np.zeros(self.chunk, np.int32)
+                valid[:fill] = 1
+                piece = jax.device_put(keys), jax.device_put(valid), fill
+            yield piece
 
     def route_stream(
         self,
@@ -372,26 +396,43 @@ class ChunkedRouter:
         outs: Optional[list] = [] if on_chunk is None else None
         pending = None
         n = 0
-        it = self._device_pieces(chunks)
-        cur = next(it, None)
-        while cur is not None:
-            keys_d, valid_d, n_valid = cur
-            self._carry, choices = self._step(
-                self._carry, keys_d, valid_d, self._seeds, self._icap
-            )
-            cur = next(it, None)  # prefetch overlaps the async step above
+        with TraceAnnotation("router.stream"):
+            it = self._device_pieces(chunks)
+            cur = next(it, None)
+            while cur is not None:
+                keys_d, valid_d, n_valid = cur
+                with TraceAnnotation("router.dispatch", chunk=self.n_chunks):
+                    self._carry, choices = self._step(
+                        self._carry, keys_d, valid_d, self._seeds, self._icap
+                    )
+                cur = next(it, None)  # prefetch overlaps the async step above
+                if pending is not None:
+                    self._pull(pending, outs, on_chunk)
+                pending = (choices, n_valid, self.n_chunks)
+                self.n_chunks += 1
+                n += n_valid
             if pending is not None:
-                self._emit(pending, outs, on_chunk)
-            pending = (choices, n_valid)
-            n += n_valid
-        if pending is not None:
-            self._emit(pending, outs, on_chunk)
+                self._pull(pending, outs, on_chunk)
         self.n_routed += n
         if outs is not None:
             return (
                 np.concatenate(outs) if outs else np.empty(0, np.int32)
             )
         return n
+
+    def _pull(self, pending, outs, on_chunk) -> None:
+        """Hand a dispatched chunk's assignments on: `_emit`'s trim, wait
+        and device->host copy inside the chunk's `router.pull` span, and
+        `on_chunk` after the span has closed."""
+        choices, n_valid, chunk = pending
+        host: list = []
+        with TraceAnnotation("router.pull", chunk=chunk):
+            self._emit(
+                (choices, n_valid), outs,
+                None if on_chunk is None else host.append,
+            )
+        for a in host:
+            on_chunk(a)
 
     @staticmethod
     def _emit(pending, outs, on_chunk) -> None:
