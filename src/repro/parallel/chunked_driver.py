@@ -34,7 +34,10 @@ Per-block semantics for the adaptive policies mirror ``online_head_tables``
 exactly: emit the head table from the summary *before* the block (stale by
 <= block messages), route, cond-decay on period boundaries, then update the
 tracker per element — one shared emit (estimation.online_ss_head_table), so
-the chunked and one-shot paths cannot drift.
+the chunked and one-shot paths cannot drift.  The summary never depends on
+routing, so the step runs the whole chunk's tracker pass first, as one
+Pallas kernel (kernels/ss_update.ss_update_chunk, the summary held on chip),
+and the block scan emits each table from that pass's snapshot of the block.
 
 ``ChunkedShardedRouter`` extends the same idea across the sharded router's
 load-sync epochs: each chunk is exactly one epoch (n_shards * sync_period *
@@ -50,7 +53,8 @@ call and, per chunk, ``router.put`` (rebuffer copy + ``device_put``),
 device->host copy); the last two carry ``chunk=<n>`` from
 ``ChunkedRouter.n_chunks``.  None of them encloses the caller's iterator or
 ``on_chunk``.  Inside the step, ``jax.named_scope`` marks ``ss_head_table``
-and ``ss_update`` (adaptive policies) and ``waterfill`` (W-Choices, in
+and ``ss_update`` (adaptive policies; the latter wraps the one ``ss_update``
+kernel per chunk) and ``waterfill`` (W-Choices, in
 ``route_core.route_block``); the step's program keeps jit's name,
 ``jit_step``.
 
@@ -70,10 +74,8 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.estimation import (
     OnlineSS,
-    online_ss_decay,
     online_ss_head_table,
     online_ss_init,
-    online_ss_update,
 )
 from repro.core.hashing import derive_seeds
 from repro.kernels.route_core import (
@@ -82,6 +84,7 @@ from repro.kernels.route_core import (
     head_table_ncand,
     route_block,
 )
+from repro.kernels.ss_update import ss_update_chunk
 
 __all__ = [
     "POLICIES",
@@ -143,11 +146,13 @@ def _build_step(cfg: _StepConfig) -> Callable:
 
     step(carry, keys (chunk,) i32, valid (chunk,) i32, seeds, icap) ->
     (carry', choices (chunk,)).  carry = (loads (1, n_workers) f32, OnlineSS
-    or None, global block counter () i32).  Pad lanes (valid == 0) route as
-    tail messages but are masked out of the histogram, the tracker update,
-    and (by never carrying W_SENTINEL) the water-fill rank sequence — they
-    cannot perturb any real decision, which is what makes a padded final
-    chunk bit-exact to the unpadded one-shot scan.
+    or None, global block counter () i32).  For the adaptive policies the
+    chunk's tracker pass runs first (one kernel) and block b's head table
+    reads its snapshot b.  Pad lanes (valid == 0) route as tail messages but
+    are masked out of the histogram, the tracker update, and (by never
+    carrying W_SENTINEL) the water-fill rank sequence — they cannot perturb
+    any real decision, which is what makes a padded final chunk bit-exact to
+    the unpadded one-shot scan.
     """
     nblk = cfg.chunk // cfg.block
     w_mode = cfg.policy == "w_choices"
@@ -155,20 +160,36 @@ def _build_step(cfg: _StepConfig) -> Callable:
     eid = jnp.arange(cfg.n_workers, dtype=jnp.int32)
 
     def step(carry, keys_c, valid_c, seeds, icap):
+        loads, state, b0 = carry
         kb_all = keys_c.astype(jnp.int32).reshape(nblk, cfg.block)
         vb_all = valid_c.astype(jnp.int32).reshape(nblk, cfg.block)
+        if adaptive:
+            # The summary's sequence never depends on routing: one kernel
+            # runs the chunk's whole pass first and hands the block scan
+            # each block's snapshot.
+            with jax.named_scope("ss_update"):
+                snap_k, snap_c, snap_t, state = ss_update_chunk(
+                    state, kb_all, vb_all, b0,
+                    decay_period=cfg.decay_period,
+                )
+            xs = (kb_all, vb_all, snap_k, snap_c, snap_t)
+        else:
+            xs = (kb_all, vb_all)
 
         def blk(c, inp):
-            loads, state, b = c
-            kb, vb = inp
+            loads, b = c
+            kb, vb, *snap = inp
             if adaptive:
                 # Table emitted from the state BEFORE this block (stale by
-                # <= block messages) — online_head_tables' exact emit.
+                # <= block messages) — online_head_tables' exact emit, which
+                # reads no errors.
+                sk, sc, st = snap
                 with jax.named_scope("ss_head_table"):
                     tk, tn = online_ss_head_table(
-                        state, cfg.n_workers, d=cfg.d, d_max=cfg.d_max,
-                        theta=cfg.theta, slack=cfg.slack,
-                        min_count=cfg.min_count, any_worker=w_mode,
+                        OnlineSS(sk, sc, None, st), cfg.n_workers,
+                        d=cfg.d, d_max=cfg.d_max, theta=cfg.theta,
+                        slack=cfg.slack, min_count=cfg.min_count,
+                        any_worker=w_mode,
                     )
                 nc = head_table_ncand(kb, tk, tn, cfg.d, cfg.d_max)
                 nc = jnp.where(vb > 0, nc, jnp.int32(cfg.d))
@@ -184,27 +205,10 @@ def _build_step(cfg: _StepConfig) -> Callable:
             # block reproduces route_block's update bit-for-bit.
             hist = ((choice[:, None] == eid) & (vb[:, None] > 0))
             loads = loads + hist.astype(jnp.float32).sum(axis=0)[None, :]
-            if adaptive:
-                if cfg.decay_period > 0:
-                    do = (b * cfg.block) % cfg.decay_period < cfg.block
-                    state = lax.cond(
-                        (b > 0) & do, online_ss_decay, lambda s: s, state
-                    )
+            return (loads, b + jnp.int32(1)), choice
 
-                def upd(s, kv):
-                    k, v = kv
-                    # weight=0 would still evict a slot; skip pads entirely
-                    return lax.cond(
-                        v > 0, lambda s: online_ss_update(s, k),
-                        lambda s: s, s,
-                    ), None
-
-                with jax.named_scope("ss_update"):
-                    state = lax.scan(upd, state, (kb, vb))[0]
-            return (loads, state, b + jnp.int32(1)), choice
-
-        carry, choices = lax.scan(blk, carry, (kb_all, vb_all))
-        return carry, choices.reshape(-1)
+        (loads, b), choices = lax.scan(blk, (loads, b0), xs)
+        return (loads, state, b), choices.reshape(-1)
 
     return step
 

@@ -18,10 +18,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
+from repro.core.estimation import OnlineSS
+from repro.kernels import platform
 from repro.kernels.adaptive_route import adaptive_route_online, w_route
 from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch, moe_pkg_dispatch
 from repro.kernels.pkg_route import pkg_route
-from repro.parallel.chunked_driver import ChunkedRouter
+from repro.kernels.ss_update import ss_update_chunk
+from repro.parallel.chunked_driver import ChunkedRouter, clear_step_cache
 from repro.parallel.sharded_router import routed_step_roofline
 from repro.roofline.analysis import V5E
 
@@ -61,9 +64,24 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+@pytest.fixture
+def tpu_kernels(monkeypatch):
+    """Kernels called with `interpret=None` compile for the TPU during the
+    test: the CPU backend otherwise resolves them to interpret mode.  Traces
+    made either way are dropped around it."""
+    monkeypatch.setattr(platform, "interpret_default", lambda: False)
+    clear_step_cache()
+    jax.clear_caches()
+    yield
+    clear_step_cache()
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
-def test_chunk_step_compiles(chip, policy):
-    """ChunkedRouter's XLA chunk step, the main path chip_smoke.py runs."""
+def test_chunk_step_compiles(chip, tpu_kernels, policy):
+    """ChunkedRouter's chunk step, the main path chip_smoke.py runs: an XLA
+    program, which for the adaptive policies holds the Space-Saving
+    kernel."""
     router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK)
     carry = jax.tree.map(
         lambda a: _spec(chip, a.shape, a.dtype), router._carry
@@ -72,6 +90,22 @@ def test_chunk_step_compiles(chip, policy):
     chunk = _spec(chip, (CHUNK,), jnp.int32)
     compiled = router._step.lower(carry, chunk, chunk, seeds, None).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    assert ("tpu_custom_call" in compiled.as_text()) == (policy != "pkg")
+
+
+@pytest.mark.parametrize("decay_period", [0, 1000])
+def test_ss_update_chunk_compiles(chip, decay_period):
+    """One chunk's Space-Saving pass, the summary held on chip."""
+    state = OnlineSS(*(_spec(chip, s, jnp.int32) for s in [(H,)] * 3 + [()]))
+    rows = _spec(chip, (CHUNK // BLOCK, BLOCK), jnp.int32)
+    compiled = _compile(
+        lambda s, k, v, b: ss_update_chunk(
+            s, k, v, b, decay_period=decay_period, interpret=False
+        ),
+        state, rows, rows, _spec(chip, (), jnp.int32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_pkg_route_compiles(chip):
