@@ -24,7 +24,10 @@ Two kinds of estimators live here:
    update/decay transitions, so the tracker rides inside a partitioner's
    lax.scan carry and head detection happens per message with no pre-pass.
    adaptive_d_counts is the integer-exact d(k) rule shared by the offline
-   pre-pass and the scan so both paths make bit-identical decisions.
+   pre-pass and the scan so both paths make bit-identical decisions; its
+   int32 path is exact for every count <= total < 2**31.  The remaining
+   int32 limit is the summary's own: OnlineSS counts and total wrap at 2**31
+   events.
 """
 from __future__ import annotations
 
@@ -232,8 +235,9 @@ def adaptive_d_counts(
     slacks used in practice), so the offline
     pre-pass (numpy int64) and the scan-carry online path (jnp int32) land on
     the same d(k) even when slack*p*W sits exactly on a ceil boundary, where
-    float rounding would otherwise split them.  Works on numpy and jnp inputs;
-    int32 callers need slack_num * n_workers * count < 2**31.
+    float rounding would otherwise split them.  Works on numpy and jnp inputs.
+    The jnp path is exact in int32 for every 0 <= count <= total < 2**31:
+    `_ceil_mul_div` never forms slack_num * n_workers * count.
     """
     from fractions import Fraction
 
@@ -244,10 +248,33 @@ def adaptive_d_counts(
         den = np.int64(s_den) * np.int64(total)
         need = -((-num) // max(int(den), 1))
         return np.clip(need, d_base, d_max).astype(np.int32)
-    num = jnp.int32(s_num * n_workers) * counts
-    den = jnp.int32(s_den) * total
-    need = -((-num) // jnp.maximum(den, 1))  # ceil-div, defined at total=0
+    # ceil(x / (s_den * t)) == ceil(ceil(x / t) / s_den); t >= 1 keeps the
+    # rule defined at total = 0
+    x = _ceil_mul_div(counts, jnp.maximum(total, 1), s_num * n_workers)
+    need = -((-x) // s_den)
     return jnp.clip(need, d_base, d_max).astype(jnp.int32)
+
+
+def _ceil_mul_div(c, t, a: int):
+    """ceil(a * c / t) in int32 for 0 <= c <= t < 2**31 and a static a >= 0,
+    without forming a * c: long multiplication over a's bits, keeping
+    q * t + r == (a's leading bits) * c with 0 <= r < t.  Doubling r tests
+    2r >= t as r >= t - r and adding c tests r + c >= t as r >= t - c, so no
+    intermediate leaves [0, t]."""
+    c = jnp.asarray(c, jnp.int32)
+    t = jnp.asarray(t, jnp.int32)
+    t_c = t - c
+    q = jnp.zeros_like(c)
+    r = jnp.zeros_like(c)
+    for bit in f"{a:b}":
+        up = r >= t - r
+        r = jnp.where(up, r - (t - r), r + r)
+        q = q + q + up.astype(jnp.int32)
+        if bit == "1":
+            up = r >= t_c
+            r = jnp.where(up, r - t_c, r + c)
+            q = q + up.astype(jnp.int32)
+    return q + (r > 0).astype(jnp.int32)
 
 
 class SpaceSavingTracker:

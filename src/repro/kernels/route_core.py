@@ -229,7 +229,8 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
 
     Loads are fetched and written back MXU-style: one-hot(cand) @ loads for
     the candidate lookup, ones @ one-hot(choice) for the histogram update —
-    no gathers or scatters (DESIGN.md SS2/SS7).
+    no gathers or scatters (DESIGN.md SS2/SS7).  The fetch, the lane masks
+    and the candidate argmin run in the `candidate_fetch` named scope.
 
     `inv_cap` (optional (1, n_entities) f32 reciprocal-capacity row) makes
     every comparison capacity-normalized: the fetch reads the normalized
@@ -250,23 +251,24 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
     """
     V, d_max = cand.shape
     eid = jnp.arange(n_entities, dtype=jnp.int32)
-    onehot_c = (cand[..., None] == eid).astype(jnp.float32)  # (V, d_max, n)
-    row = loads if inv_cap is None else loads * inv_cap
-    # HIGHEST: a TPU f32 matmul otherwise runs one bf16 pass, which rounds
-    # any load above 256 (8 mantissa bits); full f32 fetches it exactly
-    lc = jax.lax.dot_general(
-        onehot_c.reshape(V * d_max, n_entities),
-        row.reshape(n_entities, 1),
-        (((1,), (0,)), ((), ())),
-        precision=lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    ).reshape(V, d_max)
-    lc, is_w = _mask_and_flag(lc, nc, d_max, w_mode)
-    # argmin with ties -> first candidate, as min + lowest matching column
-    col = lax.broadcasted_iota(jnp.int32, (V, d_max), 1)
-    m = jnp.min(lc, axis=-1, keepdims=True)
-    sel = jnp.min(jnp.where(lc == m, col, d_max), axis=-1)
-    choice = pick_lane(cand, sel)
+    with jax.named_scope("candidate_fetch"):
+        onehot_c = (cand[..., None] == eid).astype(jnp.float32)  # (V, d_max, n)
+        row = loads if inv_cap is None else loads * inv_cap
+        # HIGHEST: a TPU f32 matmul otherwise runs one bf16 pass, which rounds
+        # any load above 256 (8 mantissa bits); full f32 fetches it exactly
+        lc = jax.lax.dot_general(
+            onehot_c.reshape(V * d_max, n_entities),
+            row.reshape(n_entities, 1),
+            (((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        ).reshape(V, d_max)
+        lc, is_w = _mask_and_flag(lc, nc, d_max, w_mode)
+        # argmin with ties -> first candidate, as min + lowest matching column
+        col = lax.broadcasted_iota(jnp.int32, (V, d_max), 1)
+        m = jnp.min(lc, axis=-1, keepdims=True)
+        sel = jnp.min(jnp.where(lc == m, col, d_max), axis=-1)
+        choice = pick_lane(cand, sel)
     if w_mode:
         with jax.named_scope("waterfill"):
             head_choice = waterfill_assign(

@@ -54,8 +54,9 @@ device->host copy); the last two carry ``chunk=<n>`` from
 ``ChunkedRouter.n_chunks``.  None of them encloses the caller's iterator or
 ``on_chunk``.  Inside the step, ``jax.named_scope`` marks ``ss_head_table``
 and ``ss_update`` (adaptive policies; the latter wraps the one ``ss_update``
-kernel per chunk) and ``waterfill`` (W-Choices, in
-``route_core.route_block``); the step's program keeps jit's name,
+kernel per chunk), ``candidate_fetch`` (every policy: the load fetch, lane
+masks and candidate argmin) and ``waterfill`` (W-Choices), the last two in
+``route_core.route_block``; the step's program keeps jit's name,
 ``jit_step``.
 
 Import directly (``from repro.parallel.chunked_driver import ChunkedRouter``);

@@ -5,6 +5,9 @@ The hypothesis property test checks the classic SPACESAVING guarantees hold
 for the array-state implementation on *drifting* streams: estimates are upper
 bounds, over-estimation never exceeds total/capacity (the m/k bound), and the
 error-corrected count is a lower bound.
+
+adaptive_d_counts' int32 path is exact up to total < 2**31; the summary's own
+int32 counts and total wrap at 2**31 events, the int32 limit that remains.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -136,6 +139,36 @@ def test_adaptive_d_counts_integer_exact():
     b = adaptive_d_counts(jnp.asarray(counts, jnp.int32), jnp.int32(20_000), 100,
                           d_base=2, d_max=16)
     np.testing.assert_array_equal(a, np.asarray(b))
+
+
+@pytest.mark.parametrize("W", [100, 1_000])
+@pytest.mark.parametrize("slack", [2.0, 1.5])
+def test_adaptive_d_counts_int32_matches_int64(W, slack):
+    """The jnp (int32) path gives the numpy (int64) path's d(k) for counts
+    <= total < 2**31: around 2**31 / (s_num * W), where slack_num * W *
+    count first leaves int32, and up to 2**31 - 1.  A product formed in
+    int32 wraps there (at W = 100 and slack 2 a key at 9.3% of 118 M events
+    read d = 2 in place of 19)."""
+    s_num = 3 if slack == 1.5 else 2
+    edge = (1 << 31) // (s_num * W)
+    rng = np.random.default_rng(W)
+    for total in [edge, 2 * edge + 1, 118_000_000, (1 << 31) - 12_345, (1 << 31) - 1]:
+        counts = np.concatenate([
+            np.arange(edge - 3, edge + 4),
+            np.arange(total - 3, total + 1),
+            rng.integers(0, total + 1, 500),
+        ])
+        counts = counts[(counts >= 0) & (counts <= total)]
+        for d_max in (16, W):
+            want = adaptive_d_counts(counts, total, W, d_max=d_max, slack=slack)
+            got = adaptive_d_counts(
+                jnp.asarray(counts, jnp.int32), jnp.int32(total), W,
+                d_max=d_max, slack=slack,
+            )
+            np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(adaptive_d_counts(
+        jnp.int32(11_000_000), jnp.int32(118_000_000), 100, d_max=100
+    )) == 19
 
 
 def test_offline_and_online_d_choices_agree_on_stationary_streams():

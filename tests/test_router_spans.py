@@ -4,7 +4,7 @@
 `router.put`, `router.dispatch` and `router.pull` (the last two carrying the
 chunk id) into the profiler's trace; the chunk step's program is `jit_step`
 and its HLO carries the `ss_head_table`, `ss_update` and `waterfill` scopes
-where the policy runs them.  Read back here from a CPU trace with
+where the policy runs them, and `candidate_fetch` in every policy.  Read back here from a CPU trace with
 `jax.profiler.ProfileData`, as the chip benchmark reads a TPU one.
 """
 import glob
@@ -120,3 +120,17 @@ def test_step_program_and_scopes(policy):
         "w_choices": set(SCOPES),
     }[policy]
     assert found == want
+
+
+@pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
+def test_step_has_candidate_fetch_scope(policy):
+    """`route_block`'s load fetch, lane masks and candidate argmin carry the
+    `candidate_fetch` scope in every policy's step, beside `waterfill`,
+    never inside it."""
+    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK, d_max=W)
+    keys = jnp.zeros(CHUNK, jnp.int32)
+    lowered = router._step.lower(router._carry, keys, keys, router._seeds, None)
+    paths = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+    fetch = [p for p in paths if "/candidate_fetch/" in p]
+    assert fetch
+    assert not any("/waterfill/" in p for p in fetch)

@@ -77,12 +77,18 @@ def tpu_kernels(monkeypatch):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
-def test_chunk_step_compiles(chip, tpu_kernels, policy):
+@pytest.mark.parametrize(
+    "policy,d_max",
+    [("pkg", 8), ("d_choices", 8), ("w_choices", 8), ("d_choices", W)],
+    ids=["pkg", "d_choices", "w_choices", "d_choices-d_max100"],
+)
+def test_chunk_step_compiles(chip, tpu_kernels, policy, d_max):
     """ChunkedRouter's chunk step, the main path chip_smoke.py runs: an XLA
     program, which for the adaptive policies holds the Space-Saving
-    kernel."""
-    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK)
+    kernel.  D-Choices also at d_max = W, the benchmark's wp_dchoices_w100:
+    a (128, 100) candidate table and a (12,800 x 100) one-hot fetch per
+    block."""
+    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK, d_max=d_max)
     carry = jax.tree.map(
         lambda a: _spec(chip, a.shape, a.dtype), router._carry
     )
