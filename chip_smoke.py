@@ -20,6 +20,11 @@ One chip, in order:
    ChunkedRouter.route_stream with a histogram sink.  pkg routes --events
    events, d_choices and w_choices --adaptive-events.  The histogram must sum
    to the events routed and no assignment may fall outside [0, W).
+3. MoE W-Choices dispatch: moe_adaptive_dispatch(w_mode=True) compiled on
+   the chip at the router widths of Mixtral-8x7B (k=2 of E=8 experts) and
+   OLMoE-1B-7B (k=8 of E=64), one 8,192-token sequence with a hot expert,
+   must equal ref_moe_adaptive_dispatch on the host CPU bit for bit (expert
+   ids, gates and loads).
 
 --four-chips runs only sharded_route over make_stream_mesh(4), one shard per
 chip, at sync_period 1 and 16 over 4 x 1,048,576 WP events, and compares it
@@ -55,6 +60,10 @@ SYNC_PERIODS = (1, 16)
 # Space-Saving head tracker of d_choices / w_choices: the ChunkedRouter
 # defaults, named here so the one-shot reference builds the same tables
 SS_CAPACITY, MIN_COUNT, SLACK, D_MAX = 256, 8, 2.0, 8
+# MoE router widths (name, experts E, slots per token k); one 8k-token
+# sequence in blocks of 256 tokens, so a block holds 256*k routing lanes
+MOE_WIDTHS = (("mixtral", 8, 2), ("olmoe", 64, 8))
+MOE_TOKENS, MOE_BLOCK = 8192, 256
 
 
 class SmokeFailure(Exception):
@@ -189,6 +198,59 @@ def stream_phase(policy: str, events: int, seed: int) -> None:
     )
 
 
+def moe_candidates(E: int, k: int, seed: int):
+    """Router-ranked (T, k, 2) candidates and gates of a softmax router
+    whose expert 0 is hot, so the W-Choices head tables flag it."""
+    rng = np.random.default_rng(seed)
+    logits = rng.standard_normal((MOE_TOKENS, E)).astype(np.float32)
+    logits[:, 0] += 3.0
+    probs = np.exp(logits - logits.max(axis=1, keepdims=True))
+    probs /= probs.sum(axis=1, keepdims=True)
+    order = np.argsort(-probs, axis=1, kind="stable")[:, : 2 * k]
+    gates = np.take_along_axis(probs, order, axis=1)
+    return (order.reshape(MOE_TOKENS, k, 2).astype(np.int32),
+            gates.reshape(MOE_TOKENS, k, 2).astype(np.float32))
+
+
+def moe_phase(seed: int, dev) -> None:
+    """moe_adaptive_dispatch(w_mode=True) on the chip == its host oracle."""
+    import jax
+
+    from repro.core.estimation import W_SENTINEL
+    from repro.kernels import ref
+    from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch
+    from repro.models.moe import expert_head_tables
+
+    cpu = jax.devices("cpu")[0]
+    for name, E, k in MOE_WIDTHS:
+        cand, gates = moe_candidates(E, k, seed)
+        with jax.default_device(cpu):
+            tk, tn = expert_head_tables(
+                jax.numpy.asarray(cand[:, 0, 0]), E, MOE_BLOCK, d_base=2,
+                d_max=2, any_worker=True,
+            )
+            oracle = ref.ref_moe_adaptive_dispatch(
+                cand, gates, tk, tn, E, d_base=2, d_max=2, block=MOE_BLOCK,
+                w_mode=True,
+            )
+        args = jax.device_put((cand, gates, tk, tn), dev)
+        kernel = moe_adaptive_dispatch(
+            *args, E, d_base=2, d_max=2, block=MOE_BLOCK, interpret=False,
+            w_mode=True,
+        )
+        heads = np.asarray(tk)[np.asarray(tn) == int(W_SENTINEL)]
+        spilled = int(np.isin(cand[:, 0, 0], heads).sum()) * k
+        equal = [bool(np.array_equal(np.asarray(a), np.asarray(b)))
+                 for a, b in zip(kernel, oracle)]
+        emit(
+            phase=f"moe/{name}", tokens=MOE_TOKENS, experts=E, k=k,
+            lanes_per_block=MOE_BLOCK * k, head_lanes_at_most=spilled,
+            idx_eq_ref=equal[0], gates_eq_ref=equal[1], loads_eq_ref=equal[2],
+        )
+        check(spilled > 0, f"moe/{name}: no head tokens, water-fill unused")
+        check(all(equal), f"moe/{name}: moe_adaptive_dispatch != ref")
+
+
 # -- four chips ---------------------------------------------------------------
 
 
@@ -305,6 +367,7 @@ def main() -> int:
             stream_phase("pkg", args.events, args.seed)
             for policy in ("d_choices", "w_choices"):
                 stream_phase(policy, args.adaptive_events, args.seed)
+            moe_phase(args.seed, dev)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -16,10 +16,11 @@ and routes by a *global* masked argmin over the full (1, n_workers) loads row
 (pad lanes hold the MASK sentinel, ties break to the lowest worker index), so
 n_workers need not be a power of two nor fit one VPU lane group.  The r-th
 head lane of a block takes the r-th argmin of the sequential water-fill of
-that row (route_core.waterfill_assign: one masked argmin per head lane) — so
-head messages reproduce w_choices_partition's global step exactly from
-block-start loads instead of piling a whole block onto a single stale
-minimum.
+that row (route_core.waterfill_levels, a loop-free count of picks per load
+level; with capacities route_core.waterfill_assign, one masked argmin per
+head lane) — so head messages reproduce w_choices_partition's global step
+exactly from block-start loads instead of piling a whole block onto a
+single stale minimum.
 
 The per-block machinery (hash, one-hot load fetch, mask, argmin, water-fill,
 histogram update) all lives in kernels/route_core.py — ONE routing core

@@ -18,11 +18,13 @@ formulation (one-hot-matmul load fetch + histogram update, no gathers); the
 host-side `oracle_block_step` is the gather-based twin with identical mask /
 sentinel / tie-break semantics.  Both share `_mask_and_flag` and
 `head_table_ncand`, so the masks and the head-table lookup cannot drift.  The
-W-sentinel water-fill has two formulations of one definition ("take the
-global argmin, add one"): `waterfill_assign`, sequential and Mosaic-lowerable,
-on the kernel side, and the loop-free `waterfill_picks` (top_k) in the
-oracle — so the bit-exactness contracts in tests/test_kernels.py compare
-them.
+W-sentinel water-fill has three formulations of one definition ("take the
+global argmin, add one"), each checked against the others bit for bit in
+tests/test_kernels.py.  The kernel side runs `waterfill_levels` without
+capacities (loop-free: it counts picks per integer load level) and
+`waterfill_assign` with them (sequential: one argmin per head lane); both
+lower under Mosaic, so the XLA and the Pallas callers share them.  The
+oracle runs the loop-free `waterfill_picks` (top_k).
 
 Vocabulary: `n_entities` is the number of routing targets — stream workers
 for the routers, experts for MoE dispatch.  Loads are integer counts in f32
@@ -47,6 +49,7 @@ __all__ = [
     "hash_candidates",
     "waterfill_picks",
     "waterfill_assign",
+    "waterfill_levels",
     "pick_lane",
     "head_table_ncand",
     "route_block",
@@ -119,7 +122,8 @@ def waterfill_picks(loads, *, n_workers, block, inv_cap=None):
     ties land on the lowest worker, then ascending t, matching argmin's
     first-index rule at every step).  Loads are integer counts in f32, so
     values and ties are IEEE-exact.  The oracle uses this formulation; the
-    kernels use `waterfill_assign`, which Mosaic can lower.
+    kernels use `waterfill_levels` and `waterfill_assign`, which Mosaic can
+    lower.
 
     With `inv_cap` (a (1, n_workers) reciprocal-capacity row, arXiv
     1705.09073) the argmin runs over capacity-normalized values
@@ -184,8 +188,9 @@ def waterfill_assign(loads, is_w, *, n_workers, inv_cap=None):
     the lowest worker index, and the capacity-normalized value is the same
     ``(L_j + t) * inv_cap_j`` product the host scan forms.  The loop runs V
     steps of lane reductions and selects only, so it compiles under Mosaic
-    (no top_k, sort, cumsum or gather); `waterfill_picks` is the loop-free
-    formulation of the same sequence that the host oracles use.
+    (no top_k, sort, cumsum or gather).  route_block runs it only with
+    capacities, whose values are not integers; without them it runs the
+    loop-free `waterfill_levels`.
     """
     V = is_w.shape[0]
     row, icap, w_pad = _padded_row(loads, inv_cap, n_workers)
@@ -215,6 +220,60 @@ def waterfill_assign(loads, is_w, *, n_workers, inv_cap=None):
     return lax.fori_loop(0, V, body, init)[2].reshape(V)
 
 
+def waterfill_levels(loads, is_w, *, n_workers):
+    """`waterfill_assign` without capacities and without a loop: the same
+    (V,) int32 destinations, found by counting picks per load level.
+
+    It needs integer loads (counts in f32, or MASK): a fractional row,
+    such as the sharded router's weighted load-sync leaves, would truncate
+    in D below and pick other workers than the definition; such callers
+    hand route_block an inv_cap row of ones, which takes `waterfill_assign`.
+    With integer loads every value the water-fill compares is an integer.
+    Worker j's t-th pick happens at level D_j + t, D_j = L_j - min(L), and
+    level s holds one pick of each worker with D_j <= s, in index order
+    (argmin's lowest-index rule).  The least-loaded worker alone supplies
+    one pick per level, so the first V picks lie below level V and D clips
+    at V (pad and masked lanes clip there too).  For head lane i of rank r
+    (heads before it in lane order):
+
+      cle[s]  = sum_j max(s + 1 - D_j, 0)      picks at levels <= s
+      lvl     = #{s : cle[s] <= r}             the level of pick r
+      off     = r - sum_j max(lvl - D_j, 0)    its place within the level
+      pick    = the off-th worker (index order) with D_j <= lvl
+
+    The place within a level is an exclusive prefix count over workers, a
+    matmul of 0/1 bf16 operands into an f32 accumulator, so exact.  Only
+    compares, selects, lane and sublane sums and one matmul: no top_k, sort,
+    cumsum or gather, so it lowers under Mosaic as route_block does.  A row
+    whose every lane is masked gives 0, as the definition does there (MASK
+    + t rounds to MASK, so the lowest index wins every pick).
+    """
+    V = is_w.shape[0]
+    row, _, w_pad = _padded_row(loads, None, n_workers)
+    m = jnp.min(row, axis=1, keepdims=True)
+    d_row = jnp.minimum(row - m, V).astype(jnp.int32)  # (1, w_pad)
+    wi = lax.broadcasted_iota(jnp.int32, (w_pad, w_pad), 0)
+    wj = lax.broadcasted_iota(jnp.int32, (w_pad, w_pad), 1)
+    d_col = jnp.sum(jnp.where(wi == wj, d_row, 0), axis=1, keepdims=True)
+    s_row = lax.broadcasted_iota(jnp.int32, (1, V), 1)
+    cle = jnp.sum(jnp.maximum(s_row + 1 - d_col, 0), axis=0, keepdims=True)
+    lane_i = lax.broadcasted_iota(jnp.int32, (V, V), 0)
+    lane_k = lax.broadcasted_iota(jnp.int32, (V, V), 1)
+    heads = is_w.astype(jnp.int32).reshape(1, V)
+    rank = jnp.sum(jnp.where(lane_k < lane_i, heads, 0), axis=1, keepdims=True)
+    lvl = jnp.sum((cle <= rank).astype(jnp.int32), axis=1, keepdims=True)
+    off = rank - jnp.sum(jnp.maximum(lvl - d_row, 0), axis=1, keepdims=True)
+    le = d_row <= lvl  # (V, w_pad): the workers with a pick at lvl
+    before = jnp.dot(
+        le.astype(jnp.bfloat16), (wi < wj).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    hit = le & (before == off) & (m < MASK)
+    wid = lax.broadcasted_iota(jnp.int32, (V, w_pad), 1)
+    pick = jnp.min(jnp.where(hit, wid, w_pad), axis=1)
+    return jnp.where(is_w & (pick < w_pad), pick, 0)
+
+
 def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
     """The kernel-side masked-greedy routing core for one vector block.
 
@@ -242,12 +301,17 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
 
     With w_mode (static), lanes with nc == W_SENTINEL take the W-Choices
     path: the r-th such lane of the block gets the r-th water-fill argmin of
-    the block-start loads row (waterfill_assign), so consecutive head
-    messages spread exactly as the sequential global-argmin would.  Tail
-    lanes still read block-start loads only — the same < block staleness
-    contract as the load vector itself (DESIGN.md SS2).  w_mode=False skips
-    the water-fill entirely for callers that never emit the sentinel;
-    sentinel-free streams route identically either way.
+    the block-start loads row, so consecutive head messages spread exactly
+    as the sequential global-argmin would.  The picks come from the
+    loop-free level count (waterfill_levels) without capacities, and from
+    the sequential waterfill_assign with them (inv_cap is static, so each
+    trace holds one of the two).  The level count needs integer loads, so a
+    caller whose loads can be fractional passes inv_cap (ones at least).
+    Tail lanes still read block-start loads
+    only — the same < block staleness contract as the load vector itself
+    (DESIGN.md SS2).  w_mode=False skips the water-fill entirely for callers
+    that never emit the sentinel; sentinel-free streams route identically
+    either way.
     """
     V, d_max = cand.shape
     eid = jnp.arange(n_entities, dtype=jnp.int32)
@@ -271,9 +335,14 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
         choice = pick_lane(cand, sel)
     if w_mode:
         with jax.named_scope("waterfill"):
-            head_choice = waterfill_assign(
-                loads, is_w, n_workers=n_entities, inv_cap=inv_cap
-            )
+            if inv_cap is None:
+                head_choice = waterfill_levels(
+                    loads, is_w, n_workers=n_entities
+                )
+            else:
+                head_choice = waterfill_assign(
+                    loads, is_w, n_workers=n_entities, inv_cap=inv_cap
+                )
         choice = jnp.where(is_w, head_choice, choice)
     hist = (choice[:, None] == eid).astype(jnp.float32).sum(axis=0)
     return choice, sel, is_w, loads + hist[None, :]
@@ -291,7 +360,8 @@ def oracle_block_step(loads, cand, nc, *, n_entities, w_mode, inv_cap=None):
     matmuls, so the differential tests check the MXU formulation against
     straightforward indexing while the mask/sentinel/tie-break logic stays
     shared (same _mask_and_flag).  The W picks come from the loop-free
-    waterfill_picks, the kernel's sequential waterfill_assign's twin."""
+    waterfill_picks, the top_k twin of the kernel's waterfill_levels and
+    waterfill_assign."""
     d_max = cand.shape[-1]
     row = loads if inv_cap is None else loads * inv_cap
     lc = row[cand]  # (V, d_max)

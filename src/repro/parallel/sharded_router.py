@@ -68,6 +68,18 @@ def shard_grid(m: int, n_shards: int, sync_period: int, block: int) -> int:
     return max(-(-m_local // epoch), 1) * epoch
 
 
+def _cap_row(icap, weights, n_workers: int):
+    """The reciprocal-capacity row the block scan runs with.  Weighted
+    load-sync deltas leave fractional loads, and route_block's loop-free
+    water-fill (route_core.waterfill_levels) holds for integer loads only,
+    so shard weights without capacities route against a uniform ones row:
+    route_block then takes the sequential water-fill, and ``x * 1.0 == x``
+    keeps every compare bit-identical to the row without capacities."""
+    if icap is None and weights is not None:
+        return jnp.ones((1, n_workers), jnp.float32)
+    return icap
+
+
 def _block_scan(loads0, cand_e, nc_e, *, n_workers: int, w_mode: bool,
                 inv_cap=None):
     """One epoch on one shard: scan route_block over sync_period blocks from
@@ -96,6 +108,7 @@ def _build_sharded(n_workers, d_max, n_shards, n_epochs, sync_period, block,
         # keys_l (m_local,) — this shard's contiguous sub-stream; icap
         # (1, n_workers) replicated reciprocal capacities or None; w_s (1,)
         # this shard's load-sync delta weight or None.
+        icap = _cap_row(icap, w_s, n_workers)
         cand = hash_candidates(keys_l, seeds, n_workers)
         cand = cand.reshape(n_epochs, sync_period, block, d_max)
         nc = None if nc_l is None else nc_l.reshape(n_epochs, sync_period, block)
@@ -151,6 +164,7 @@ def _build_ref(n_workers, d_max, n_shards, n_epochs, sync_period, block,
     """Jitted single-device oracle: vmap over the shard axis, psum -> sum."""
 
     def ref_fn(keys, nc_all, seeds, icap, w):
+        icap = _cap_row(icap, w, n_workers)
         cand = hash_candidates(keys, seeds, n_workers)
         cand = cand.reshape(n_shards, n_epochs, sync_period, block, d_max)
         cand = cand.swapaxes(0, 1)  # epoch-major for the outer scan

@@ -16,7 +16,12 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch, moe_pkg_dispatch
 from repro.kernels.pkg_route import pkg_route
 from repro.kernels.rmsnorm import rmsnorm
-from repro.kernels.route_core import waterfill_assign, waterfill_picks
+from repro.kernels.route_core import (
+    MASK,
+    waterfill_assign,
+    waterfill_levels,
+    waterfill_picks,
+)
 from repro.models.moe import _pkg_choose, expert_head_tables
 
 
@@ -139,32 +144,75 @@ def test_waterfill_picks_equal_sequential_argmin(n_workers):
     assert picks.tolist() == _sequential_waterfill(loads, 96)
 
 
-@pytest.mark.parametrize("n_workers", [7, 100, 150, 200])
-@pytest.mark.parametrize("weighted", [False, True])
-def test_waterfill_assign_equals_sequential_argmin(n_workers, weighted):
-    """The kernels' sequential water-fill gives the r-th head lane of a
-    block the r-th pick of the definition — head lanes scattered over the
-    block, ties to the lowest worker, capacity-normalized values included —
-    and equals the oracle's loop-free picks."""
-    rng = np.random.default_rng(n_workers + weighted)
+def _waterfill_case(case, n_workers, rng, V=128):
+    """(loads, head flags) of one water-fill case over a V-lane block."""
     loads = rng.integers(0, 40, n_workers).astype(np.float32)
+    if case == "equal":
+        loads[:] = 17
+    elif case == "one_low":  # more than V below the rest: it takes all V picks
+        loads[:] = 3 * V
+        loads[rng.integers(n_workers)] = 2 * V - 1
+    elif case == "apart":  # loads V apart
+        loads = (rng.permutation(n_workers) * V).astype(np.float32)
+    elif case == "killed":  # masked lanes, as ChunkedRouter.kill leaves them
+        loads[rng.random(n_workers) < 0.5] = MASK
+        loads[rng.integers(n_workers)] = 5
+    elif case == "masked":  # every lane masked: the lowest index wins
+        loads[:] = MASK
+    is_w = rng.random(V) < 0.4
+    if case in ("all_heads", "one_low"):
+        is_w[:] = True
+    elif case == "no_head":
+        is_w[:] = False
+    return loads, is_w
+
+
+WATERFILL_ROWS = ("equal", "one_low", "apart", "killed", "masked",
+                  "all_heads", "no_head")
+WATERFILL_CASES = [
+    pytest.param("assign", "random", weighted, n, id=f"{weighted}-{n}")
+    for weighted in (False, True) for n in (7, 100, 128, 150, 200)
+] + [
+    pytest.param(fill, case, False, n, id=f"{fill}-{case}-{n}")
+    for fill in ("assign", "levels")
+    for case in ("random",) * (fill == "levels") + WATERFILL_ROWS
+    for n in (7, 100, 128, 150, 200)
+]
+
+
+@pytest.mark.parametrize("fill,case,weighted,n_workers", WATERFILL_CASES)
+def test_waterfill_assign_equals_sequential_argmin(fill, case, weighted,
+                                                   n_workers):
+    """The kernels' water-fills give the r-th head lane of a block the r-th
+    pick of the definition — head lanes scattered over the block, ties to
+    the lowest worker, capacity-normalized values included — and equal the
+    oracle's loop-free picks; lanes that are not heads read 0.  `assign` is
+    the sequential loop (the only one with capacities), `levels` the
+    loop-free level count route_block runs without them; the rows include
+    all loads equal, one worker more than V below the rest, loads V apart,
+    masked workers, and blocks of all heads or none."""
+    rng = np.random.default_rng(n_workers + weighted)
+    loads, is_w = _waterfill_case(case, n_workers, rng)
     icap = (
         (1.0 / rng.integers(1, 5, n_workers)).astype(np.float32)
         if weighted else None
     )
-    is_w = rng.random(128) < 0.4
     icap_row = None if icap is None else jnp.asarray(icap)[None, :]
-    got = np.asarray(waterfill_assign(
-        jnp.asarray(loads)[None, :], jnp.asarray(is_w), n_workers=n_workers,
-        inv_cap=icap_row,
-    ))
-    sim = _sequential_waterfill(loads, int(is_w.sum()), icap)
-    assert got[is_w].tolist() == sim
+    row = jnp.asarray(loads)[None, :]
+    if fill == "levels":
+        got = waterfill_levels(row, jnp.asarray(is_w), n_workers=n_workers)
+    else:
+        got = waterfill_assign(
+            row, jnp.asarray(is_w), n_workers=n_workers, inv_cap=icap_row
+        )
+    got = np.asarray(got)
+    n = int(is_w.sum())
+    assert got[is_w].tolist() == _sequential_waterfill(loads, n, icap)
+    assert not got[~is_w].any()
     picks = np.asarray(waterfill_picks(
-        jnp.asarray(loads)[None, :], n_workers=n_workers, block=128,
-        inv_cap=icap_row,
+        row, n_workers=n_workers, block=128, inv_cap=icap_row,
     ))
-    assert got[is_w].tolist() == picks[: int(is_w.sum())].tolist()
+    assert got[is_w].tolist() == picks[:n].tolist()
 
 
 @pytest.mark.parametrize("n_workers", [7, 50, 100, 200])

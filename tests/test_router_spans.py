@@ -134,3 +134,20 @@ def test_step_has_candidate_fetch_scope(policy):
     fetch = [p for p in paths if "/candidate_fetch/" in p]
     assert fetch
     assert not any("/waterfill/" in p for p in fetch)
+
+
+@pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
+def test_waterfill_scope_runs_no_loop(policy):
+    """W-Choices' step carries the `waterfill` scope with no `while` op
+    under it: without capacities the block's picks come from the level
+    count, not from one argmin per lane.  PKG and D-Choices carry no
+    water-fill op at all."""
+    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK)
+    keys = jnp.zeros(CHUNK, jnp.int32)
+    lowered = router._step.lower(router._carry, keys, keys, router._seeds, None)
+    fill = [
+        line for line in lowered.compile().as_text().splitlines()
+        if re.search(r'op_name="[^"]*/waterfill/', line)
+    ]
+    assert bool(fill) == (policy == "w_choices")
+    assert not any(re.search(r"\swhile\(", line) for line in fill)

@@ -14,6 +14,7 @@ The load-bearing invariants:
   * the shard_map program matches the vmap+sum oracle bit-exactly on a real
     8-device mesh (subprocess, slow).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -26,6 +27,7 @@ import pytest
 
 from repro.core import zipf_stream
 from repro.core.estimation import W_SENTINEL
+from repro.core.hashing import derive_seeds
 from repro.core.partitioners import (
     _head_flags,
     pkg_sharded_partition,
@@ -33,6 +35,7 @@ from repro.core.partitioners import (
 )
 from repro.core.routing import make_policy
 from repro.kernels.adaptive_route import adaptive_route, w_route
+from repro.kernels.route_core import hash_candidates, oracle_block_step
 from repro.launch.mesh import make_stream_mesh
 from repro.parallel.sharded_router import (
     ref_sharded_route,
@@ -172,6 +175,39 @@ def test_partitioner_multi_shard_emulated():
                                                sync_period=2, emulate=True))
     assert a.shape == (5000,) and a.min() >= 0 and a.max() < W
     np.testing.assert_array_equal(a, b)
+
+
+def test_weighted_sync_w_choices_matches_host_oracle():
+    # dyadic shard weights leave fractional (and exactly summed) loads rows
+    # after each sync; the head lanes must still take the definition's
+    # argmin of those rows, as the host block step (top_k water-fill) does
+    n_shards, sync, n_epochs = 4, 2, 3
+    weights = np.array([0.5, 1.25, 0.75, 1.5], np.float32)
+    keys = np.asarray(_keys(n_shards * sync * 128 * n_epochs, seed=9, z=1.8))
+    got = np.asarray(w_choices_sharded_partition(
+        keys, W, n_shards=n_shards, sync_period=sync, emulate=True,
+        shard_weights=weights,
+    ))
+    nc = np.asarray(_w_ncand(keys)).reshape(n_shards, n_epochs, sync, 128)
+    assert (nc == int(W_SENTINEL)).any(axis=-1).all()  # heads in every block
+    cand = np.asarray(hash_candidates(jnp.asarray(keys), derive_seeds(0, 2), W))
+    cand = cand.reshape(n_shards, n_epochs, sync, 128, 2)
+    step = jax.jit(functools.partial(oracle_block_step, n_entities=W,
+                                     w_mode=True))
+    want = np.zeros_like(nc)
+    loads_g = np.zeros(W, np.float32)
+    for e in range(n_epochs):
+        delta = np.zeros(W, np.float32)
+        for s in range(n_shards):
+            loads = jnp.asarray(loads_g)
+            for b in range(sync):
+                loads, want[s, e, b], _, _ = step(
+                    loads, jnp.asarray(cand[s, e, b]), jnp.asarray(nc[s, e, b])
+                )
+            delta += weights[s] * (np.asarray(loads) - loads_g)
+        loads_g = loads_g + delta
+        assert (loads_g != np.floor(loads_g)).any()  # fractional rows
+    np.testing.assert_array_equal(got, want.reshape(-1))
 
 
 def test_sharded_policy_matches_partitioner():
