@@ -7,8 +7,8 @@ pre-pass goes blind exactly when adaptivity matters.  The fully-online
 variants (tracker in the scan carry, decayed/windowed mode) follow the head
 set as it rotates.  This bench sweeps `core.streams.DRIFT_SCENARIOS`
 (stationary, half-life churn at three rates, abrupt shifts, multi-tenant mix)
-at W = 100 and reports imbalance per method, plus the online Pallas router's
-bit-exactness against its oracle.
+at W = 100 and reports imbalance per method, plus the chunk step's
+bit-exactness against its oracle on a drifting stream.
 
 `PYTHONPATH=src:. python benchmarks/bench_drift.py [--scale S] [--quick]
 [--out PATH]` writes the JSON report via the benchmarks/common.py convention
@@ -27,7 +27,8 @@ from repro.core import (
     drift_stream,
     online_head_tables,
 )
-from repro.kernels import adaptive_route_online, ref
+from repro.kernels import ref
+from repro.parallel.chunked_driver import ChunkedRouter
 
 CAPACITY = 256
 METHODS = ("pkg", "d_offline", "d_online", "w_offline", "w_online")
@@ -55,17 +56,23 @@ def _route(method: str, keys: np.ndarray, n_workers: int):
 
 
 def online_kernel_bit_exact(n_workers: int = 100, d_max: int = 8) -> bool:
-    """Head-table Pallas router vs ref.py oracle on a drifting stream."""
-    keys = jnp.asarray(drift_stream(4096, 1000, 1.8, half_life=1024, seed=7))
+    """Online D-Choices chunk step (ChunkedRouter, decayed summary) vs the
+    ref.py oracle over online_head_tables, on a drifting stream."""
+    n = 4096
+    keys = jnp.asarray(drift_stream(n, 1000, 1.8, half_life=1024, seed=7))
     tk, tn = online_head_tables(
         keys, block=128, capacity=64, n_workers=n_workers, d_max=d_max,
         decay_period=1024,
     )
-    a_k, l_k = adaptive_route_online(keys, tk, tn, n_workers, d_max=d_max)
-    a_r, l_r = ref.ref_adaptive_route_online(keys, tk, tn, n_workers, d_max=d_max)
+    router = ChunkedRouter(n_workers, "d_choices", d_max=d_max, chunk=1024,
+                           ss_capacity=64, decay_period=1024)
+    a_k = router.route_stream(np.asarray(keys))
+    a_r, l_r = ref.ref_adaptive_route_online(
+        keys, tk, tn, n_workers, d_max=d_max, chunk=n
+    )
     return bool(
-        (np.asarray(a_k) == np.asarray(a_r)).all()
-        and (np.asarray(l_k) == np.asarray(l_r)).all()
+        (a_k == np.asarray(a_r)).all()
+        and (router.loads == np.asarray(l_r[-1])).all()
     )
 
 

@@ -4,8 +4,8 @@ Wall time is measured on the jitted XLA oracle paths (the CPU production
 path; Pallas interpret mode is a correctness tool, not a timing target), with
 one interpret-mode run per kernel as a sanity check.
 
-The W-router sweep measures the in-kernel W-Choices path (DESIGN.md SS3.3
-"In-kernel W-Choices"): per-block head tables emitted with any_worker=True
+The W-router sweep measures the on-device W-Choices semantics (DESIGN.md SS3.3
+"On-device W-Choices"): per-block head tables emitted with any_worker=True
 route head keys through the global-argmin water-fill, the d_max-capped tables
 (any_worker=False) are the pre-PR-4 router, and plain PKG anchors the bottom.
 W in {8, 50, 100} x tail d in {2, 4} x {stationary, drift} streams; imbalance
@@ -27,7 +27,8 @@ import numpy as np
 
 from benchmarks.common import Row, bench_main
 from repro.core import avg_imbalance_fraction, drift_stream, online_head_tables, zipf_stream
-from repro.kernels import adaptive_route_online, ref
+from repro.kernels import ref
+from repro.parallel.chunked_driver import ChunkedRouter
 
 W_SWEEP = (8, 50, 100)
 D_SWEEP = (2, 4)
@@ -92,7 +93,8 @@ def _routers(n_workers: int):
 
 
 def w_router_bit_exact(n: int = 2048, seed: int = 3) -> bool:
-    """Pallas W-router (interpret) vs oracle: sentinel tables, assign+loads.
+    """W-Choices chunk step (ChunkedRouter) vs oracle: its carried summary's
+    sentinel tables against online_head_tables', assign+loads.
 
     Covers W=100 under drift and W=50 (not a power of two) stationary.
     """
@@ -103,16 +105,15 @@ def w_router_bit_exact(n: int = 2048, seed: int = 3) -> bool:
     ]
     for W, keys in cases:
         tk, tn = _tables(keys, W, d=2, d_max=2, any_worker=True)
-        a_k, l_k = adaptive_route_online(
-            keys, tk, tn, W, d_base=2, d_max=2, chunk=CHUNK, block=BLOCK,
-            w_mode=True,
-        )
+        router = ChunkedRouter(W, "w_choices", d=2, chunk=CHUNK, block=BLOCK,
+                               ss_capacity=CAPACITY)
+        a_k = router.route_stream(np.asarray(keys))
         a_r, l_r = ref.ref_w_route_online(
-            keys, tk, tn, W, d_base=2, d_max=2, chunk=CHUNK, block=BLOCK
+            keys, tk, tn, W, d_base=2, d_max=2, chunk=n, block=BLOCK
         )
         ok = ok and bool(
-            (np.asarray(a_k) == np.asarray(a_r)).all()
-            and (np.asarray(l_k) == np.asarray(l_r)).all()
+            (a_k == np.asarray(a_r)).all()
+            and (router.loads == np.asarray(l_r[-1])).all()
         )
     return ok
 
@@ -146,7 +147,7 @@ def collect(scale: float = 1.0, seed: int = 0) -> dict:
     report = {
         "scenarios": scenarios,
         "checks": {
-            # the tentpole claim: in-kernel W-Choices makes the device path
+            # the tentpole claim: on-device W-Choices makes the device path
             # the best-balanced one where d_max-capped routing gives out
             "w_router_beats_capped_at_w100":
                 s100["w_router_d2"] < s100["d_router"],
@@ -164,7 +165,7 @@ def run(scale: float = 1.0) -> list[Row]:
     rows = []
     key = jax.random.PRNGKey(0)
 
-    # pkg_route oracle (jitted scan)
+    # PKG oracle (jitted scan)
     n = max(int(131_072 * scale) // 1024, 2) * 1024  # chunk-divisible
     keys = jnp.asarray(zipf_stream(n, 10_000, 1.1, seed=1))
     f = jax.jit(lambda k: ref.ref_pkg_route(k, 32, chunk=1024, block=128))

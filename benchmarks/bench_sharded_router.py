@@ -92,10 +92,9 @@ def _route(keys, nc, n_workers, *, d_max, n_shards, sync_period, w_mode,
 
 
 def bit_exact_check(seed: int) -> bool:
-    """sharded(n_shards=1, sync_period=1) vs the single-core Pallas routers
-    (compiled on TPU, interpreted elsewhere) — the differential also in
-    tests/test_sharded_router.py."""
-    from repro.kernels.adaptive_route import adaptive_route, w_route
+    """sharded(n_shards=1, sync_period=1) vs the ref.py one-estimator
+    oracles — the differential also in tests/test_sharded_router.py."""
+    from repro.kernels import ref
 
     n = 2048
     keys = jnp.asarray(zipf_stream(n, 500, 1.6, seed=seed))
@@ -109,11 +108,11 @@ def bit_exact_check(seed: int) -> bool:
         )
         if w_mode:
             flags = (np.asarray(full) == int(W_SENTINEL)).astype(np.int32)
-            a_k, l_k = w_route(keys, jnp.asarray(flags), W, d=d_max,
-                               chunk=n, block=BLOCK)
+            a_k, l_k = ref.ref_w_route(keys, jnp.asarray(flags), W, d=d_max,
+                                       chunk=n, block=BLOCK)
         else:
-            a_k, l_k = adaptive_route(keys, full, W, d_max=d_max, chunk=n,
-                                      block=BLOCK)
+            a_k, l_k = ref.ref_adaptive_route(keys, full, W, d_max=d_max,
+                                              chunk=n, block=BLOCK)
         ok = ok and bool(
             (np.asarray(a_s) == np.asarray(a_k)).all()
             and (np.asarray(l_s) == np.asarray(l_k[-1])).all()

@@ -25,8 +25,8 @@ Three scenarios on the BENCH_* JSON convention:
   hash-round-trip check.  No network: the fixtures are synthesized.
 
 Bit-exactness checks (also tests/test_chunked.py): chunked == one-shot for
-every policy (pkg vs kernels.pkg_route; d/w vs online_head_tables +
-adaptive_route_online), and streaming simulate_serving == array-mode
+every policy (pkg vs ref.ref_pkg_route; d/w vs online_head_tables +
+ref.ref_adaptive_route_online), and streaming simulate_serving == array-mode
 aggregates.
 
 Scale map: events = 200k * scale, keys = events / 100 — so ``--scale 50`` is
@@ -224,15 +224,14 @@ def _chunked_eq_oneshot(seed: int) -> dict:
     import jax.numpy as jnp
 
     from repro.core.estimation import online_head_tables
-    from repro.kernels.adaptive_route import adaptive_route_online
-    from repro.kernels.pkg_route import pkg_route
+    from repro.kernels import ref
 
     n = 4096
     keys = np.concatenate(list(_spec(n, 500).stream_chunks(1024, seed=seed)))
     kj = jnp.asarray(keys)
     out = {}
     ref_pkg = np.asarray(
-        pkg_route(kj, W, d=2, seed=seed, chunk=n, block=BLOCK)[0]
+        ref.ref_pkg_route(kj, W, d=2, seed=seed, chunk=n, block=BLOCK)[0]
     )
     refs = {"pkg": ref_pkg}
     for policy in ("d_choices", "w_choices"):
@@ -242,7 +241,7 @@ def _chunked_eq_oneshot(seed: int) -> dict:
             kj, BLOCK, SS_CAP, W, d=2, d_max=D_MAX,
             decay_period=DECAY, any_worker=w_mode,
         )
-        refs[policy] = np.asarray(adaptive_route_online(
+        refs[policy] = np.asarray(ref.ref_adaptive_route_online(
             kj, tk, tn, W, d_base=2, d_max=d_max, seed=seed, chunk=n,
             block=BLOCK, w_mode=w_mode,
         )[0])

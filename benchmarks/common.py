@@ -38,8 +38,8 @@ from repro.core import (
     potc_static_partition,
     shuffle_partition,
     simulate_sources,
-    w_choices_kernel_partition,
     w_choices_partition,
+    w_choices_sharded_partition,
 )
 
 
@@ -83,8 +83,10 @@ def route(method: str, keys: np.ndarray, n_workers: int, n_keys: Optional[int] =
             return d_choices_partition(keys, n_workers, d=d, seed=seed, **kw)
         if method == "w_choices":
             return w_choices_partition(keys, n_workers, d=d, seed=seed, **kw)
-        if method == "w_choices_kernel":
-            return w_choices_kernel_partition(keys, n_workers, d=d, seed=seed, **kw)
+        if method == "w_choices_sharded":
+            return w_choices_sharded_partition(
+                keys, n_workers, d=d, seed=seed, n_shards=1, sync_period=1, **kw
+            )
         if method == "d_choices_online":
             return online_d_choices_partition(ks, n_workers, d=d, seed=seed, **kw)
         if method == "w_choices_online":

@@ -64,9 +64,9 @@ __all__ = [
 
 
 # Candidate-count value flagging "this key may go to ANY worker" (W-Choices,
-# arXiv 1510.05714) to the Pallas router and its oracle.  int32 max can never
-# collide with a real d(k) — those are clipped to d_max <= n_workers — and a
-# consumer that treats it as a plain count would mask nothing (every lane
+# arXiv 1510.05714) to the device routers and their oracles.  int32 max can
+# never collide with a real d(k) — clipped to d_max <= n_workers — and a
+# consumer that treats it as a plain count masks nothing (every lane
 # < W_SENTINEL participates), degrading to d_max choices instead of crashing.
 W_SENTINEL = np.int32(np.iinfo(np.int32).max)
 
@@ -532,7 +532,7 @@ def online_head_tables(
     decay_period: int = 0,
     any_worker: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-vector-block head tables for the Pallas adaptive router.
+    """Per-vector-block head tables for the ref.py adaptive oracles.
 
     Runs the online tracker over `keys` (N, divisible by block) and emits, for
     every block b, the summary state *before* consuming block b — so a router
@@ -543,7 +543,7 @@ def online_head_tables(
     ncand is the integer-exact d(k) for head slots and `d` otherwise, so a
     lookup miss and a tail hit are indistinguishable — both route as PKG.
     With `any_worker=True` (W-Choices) head slots carry W_SENTINEL instead of
-    d(k), flagging "route to the global least-loaded worker" to the kernel's
+    d(k), flagging "route to the global least-loaded worker" to the router's
     global-argmin path — consume such tables with the router's w_mode=True
     (DESIGN.md SS3.3).
     """

@@ -15,9 +15,7 @@ Techniques (paper SS6.2 Q1; adaptive variants from arXiv 1510.05714):
   PKG         pkg_partition           Greedy-d with key splitting (the paper)
   PKG (TPU)   pkg_partition_batched   vector-block greedy, stale-by-<V loads
   D-Choices   d_choices_partition     head keys get skew-adaptive d(k) choices
-  D (TPU)     d_choices_kernel_partition  same, on the Pallas masked-prefix path
   W-Choices   w_choices_partition     head keys may go to ANY worker
-  W (TPU)     w_choices_kernel_partition  same, on the Pallas global-argmin path
   *-sharded   pkg/d_choices/w_choices_sharded_partition  multi-device mesh,
               per-shard local loads + psum load-sync epochs (DESIGN.md §6.1)
 
@@ -63,9 +61,7 @@ __all__ = [
     "on_greedy_partition",
     "off_greedy_partition",
     "d_choices_partition",
-    "d_choices_kernel_partition",
     "w_choices_partition",
-    "w_choices_kernel_partition",
     "online_d_choices_partition",
     "online_w_choices_partition",
     "pkg_sharded_partition",
@@ -365,7 +361,7 @@ def _head_flags(
 ) -> np.ndarray:
     """THE offline W-Choices head set: SPACESAVING pre-pass + canonical
     head_counts, as per-message 0/1 flags (m,) int32.  Both W-Choices
-    partitioners (sequential scan and Pallas kernel) share this one
+    partitioners (sequential scan and sharded router) share this one
     computation — their block=1 bit-exactness contract depends on the head
     sets being identical, so the pre-pass must not fork."""
     theta = head_threshold(n_workers, d) if theta is None else theta
@@ -390,7 +386,7 @@ def _adaptive_n_cand(
     """THE offline D-Choices pre-pass: SPACESAVING + canonical head_counts +
     integer-exact d(k), as a per-message candidate-count vector (m,) int32
     (tail messages get d).  Both D-Choices partitioners (masked greedy scan
-    and Pallas kernel) share this one computation — their block=1
+    and sharded router) share this one computation — their block=1
     bit-exactness contract depends on identical d(k) schedules, so the
     pre-pass must not fork (the W analogue is _head_flags)."""
     theta = head_threshold(n_workers, d) if theta is None else theta
@@ -436,55 +432,6 @@ def d_choices_partition(
                                inv_cap=_host_inv_cap(capacities, n_workers))
 
 
-def d_choices_kernel_partition(
-    keys,
-    n_workers: int,
-    d: int = 2,
-    d_max: int = 16,
-    seed: int = 0,
-    theta: Optional[float] = None,
-    capacity: int = 1024,
-    slack: float = 2.0,
-    min_count: int = 8,
-    chunk: Optional[int] = None,
-    block: int = 128,
-    interpret: Optional[bool] = None,
-    capacities=None,
-) -> jnp.ndarray:
-    """D-CHOICES on the Pallas masked-prefix router.
-
-    Same SPACESAVING pre-pass and d(k) schedule as d_choices_partition
-    (shared _adaptive_n_cand), routed by kernels/adaptive_route.py with
-    data-dependent candidate counts.  Chunk/pad convention matches
-    w_choices_kernel_partition: one chunk of vector blocks by default,
-    padding appended as tail messages (n_cand = d), block=1 reproduces
-    d_choices_partition bit-exactly — including under `capacities` (> 0),
-    which the kernel consumes as a reciprocal-capacity row.
-    """
-    from repro.kernels.adaptive_route import adaptive_route  # kernels on core
-
-    keys_np = np.asarray(keys, dtype=np.int32)
-    d_max = max(int(min(d_max, n_workers)), d)
-    _host_inv_cap(capacities, n_workers)  # validate shape/positivity
-    n_cand = _adaptive_n_cand(
-        keys_np, n_workers, d, d_max, theta, capacity, slack, min_count
-    )
-    m = len(keys_np)
-    if chunk is None:
-        chunk = max(-(-m // block) * block, block)
-    pad = -m % chunk
-    assign, _ = adaptive_route(
-        jnp.asarray(np.pad(keys_np, (0, pad))),
-        jnp.asarray(np.pad(n_cand, (0, pad), constant_values=d)),
-        n_workers, d_max=d_max, seed=seed, chunk=chunk, block=block,
-        interpret=interpret,
-        capacities=None if capacities is None else jnp.asarray(
-            np.asarray(capacities, np.float32)
-        ),
-    )
-    return assign[:m]
-
-
 def w_choices_partition(
     keys,
     n_workers: int,
@@ -512,53 +459,6 @@ def w_choices_partition(
     cand = hash_choices(jnp.asarray(keys_np), n_workers, d=d, seed=seed)
     return _any_worker_greedy_scan(cand, jnp.asarray(is_head), n_workers,
                                    inv_cap=_host_inv_cap(capacities, n_workers))
-
-
-def w_choices_kernel_partition(
-    keys,
-    n_workers: int,
-    d: int = 2,
-    seed: int = 0,
-    theta: Optional[float] = None,
-    capacity: int = 1024,
-    min_count: int = 8,
-    chunk: Optional[int] = None,
-    block: int = 128,
-    interpret: Optional[bool] = None,
-    capacities=None,
-) -> jnp.ndarray:
-    """W-CHOICES on the Pallas router: the in-kernel global-argmin path.
-
-    Same SPACESAVING pre-pass and head set as w_choices_partition, but the
-    routing runs in kernels/adaptive_route.py — head keys are flagged with
-    estimation.W_SENTINEL and taken to the globally least-loaded worker by
-    the kernel's masked lane reduction, tail keys keep PKG's exact d-candidate
-    step.  Defaults to one chunk (a single local estimator) with vector blocks
-    of `block` keys, so loads are stale by < block messages (DESIGN.md SS2);
-    block=1 reproduces w_choices_partition bit-exactly — including under
-    `capacities` (> 0), which weights the tail argmin and the head water-fill
-    by 1/c.  The stream is padded to the chunk grid with tail messages;
-    padding is appended, so real assignments are unaffected.
-    """
-    from repro.kernels.adaptive_route import w_route  # kernels layer on core
-
-    keys_np = np.asarray(keys, dtype=np.int32)
-    _host_inv_cap(capacities, n_workers)  # validate shape/positivity
-    is_head = _head_flags(keys_np, n_workers, d, theta, capacity, min_count)
-    m = len(keys_np)
-    if chunk is None:
-        chunk = max(-(-m // block) * block, block)
-    pad = -m % chunk
-    assign, _ = w_route(
-        jnp.asarray(np.pad(keys_np, (0, pad))),
-        jnp.asarray(np.pad(is_head, (0, pad))),
-        n_workers, d=d, seed=seed, chunk=chunk, block=block,
-        interpret=interpret,
-        capacities=None if capacities is None else jnp.asarray(
-            np.asarray(capacities, np.float32)
-        ),
-    )
-    return assign[:m]
 
 
 @functools.partial(
@@ -712,8 +612,8 @@ def online_w_choices_partition(
 # the stream splits contiguously over an n_shards ("data",) mesh, each shard
 # runs the shared block-greedy core on its own local loads row, and the
 # per-shard load deltas psum every sync_period blocks (load-sync epochs).
-# n_shards=1, sync_period=1 is bit-exact to the corresponding single-core
-# kernel partitioner.
+# n_shards=1, sync_period=1 is one estimator over the whole stream: at
+# block=1 it reproduces the sequential d_choices/w_choices scans bit-exactly.
 # ---------------------------------------------------------------------------
 
 
@@ -847,7 +747,7 @@ def d_choices_sharded_partition(
     shard_weights=None,
 ) -> jnp.ndarray:
     """D-Choices on the sharded router: same offline SPACESAVING pre-pass and
-    d(k) schedule as d_choices_kernel_partition (shared _adaptive_n_cand)."""
+    d(k) schedule as d_choices_partition (shared _adaptive_n_cand)."""
     keys_np = np.asarray(keys, dtype=np.int32)
     d_max = max(int(min(d_max, n_workers)), d)
     n_cand = _adaptive_n_cand(
@@ -878,7 +778,7 @@ def w_choices_sharded_partition(
     shard_weights=None,
 ) -> jnp.ndarray:
     """W-Choices on the sharded router: same offline head set as
-    w_choices_kernel_partition (shared _head_flags); head keys take the
+    w_choices_partition (shared _head_flags); head keys take the
     water-fill global argmin over each shard's local loads view."""
     from repro.core.estimation import W_SENTINEL
 
@@ -897,11 +797,11 @@ def w_choices_sharded_partition(
 # ---------------------------------------------------------------------------
 # Chunked streaming variants (parallel/chunked_driver.py): the same route
 # core driven chunk-at-a-time with a persistent (loads, Space-Saving) carry —
-# flat memory in stream length, bit-exact to the one-shot kernels for every
-# chunk size.  The adaptive variants share the online estimation machinery
-# (online_ss_head_table emit, per-block stale tables as in
-# estimation.online_head_tables) rather than any offline pre-pass: a chunked
-# run must not require seeing the stream twice.
+# flat memory in stream length, bit-exact to the one-shot oracles of
+# kernels/ref.py for every chunk size.  The adaptive variants share the
+# online estimation machinery (online_ss_head_table emit, per-block stale
+# tables as in estimation.online_head_tables) rather than any offline
+# pre-pass: a chunked run must not require seeing the stream twice.
 # ---------------------------------------------------------------------------
 
 
@@ -914,8 +814,8 @@ def pkg_chunked_partition(
     block: int = 128,
     capacities=None,
 ) -> jnp.ndarray:
-    """PKG routed chunk-at-a-time: bit-exact to pkg_route(chunk=N) at the
-    same block size, with O(chunk) peak memory however long the stream.
+    """PKG routed chunk-at-a-time: bit-exact to ref.ref_pkg_route(chunk=N)
+    at the same block size, with O(chunk) peak memory however long the stream.
     `keys` may be an array or an iterator of array chunks."""
     from repro.parallel.chunked_driver import ChunkedRouter  # parallel on core
 
@@ -944,7 +844,8 @@ def d_choices_chunked_partition(
     """Online D-Choices routed chunk-at-a-time: the Space-Saving summary
     rides in the chunk-step carry and head tables are emitted per vector
     block (stale by <= block messages) — bit-exact to online_head_tables +
-    adaptive_route_online over the whole stream, for every chunk size."""
+    ref.ref_adaptive_route_online over the whole stream, for every chunk
+    size."""
     from repro.parallel.chunked_driver import ChunkedRouter  # parallel on core
 
     router = ChunkedRouter(
@@ -990,9 +891,7 @@ PARTITIONERS = {
     "on_greedy": on_greedy_partition,
     "off_greedy": off_greedy_partition,
     "d_choices": d_choices_partition,
-    "d_choices_kernel": d_choices_kernel_partition,
     "w_choices": w_choices_partition,
-    "w_choices_kernel": w_choices_kernel_partition,
     "d_choices_online": online_d_choices_partition,
     "w_choices_online": online_w_choices_partition,
     "pkg_sharded": pkg_sharded_partition,

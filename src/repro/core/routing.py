@@ -12,10 +12,10 @@ A RoutingPolicy is the paper's algorithm written ONCE and consumed three ways:
    fresh adapter over a stream with no completions is bit-identical to
    ``route_batch`` on the same stream (the differential contract in
    tests/test_routing.py).
-3. **Device** — the Pallas routers (kernels.adaptive_route.w_route /
-   adaptive_route) are registered as batch-only device-backed policies, so a
-   benchmark sweep can put the TPU path on the same axis as the host
-   policies.
+3. **Device** — batch-only policies backed by the sharded router
+   (parallel.sharded_router; the ``*_kernel`` names run it at one shard),
+   so a benchmark sweep can put the device path on the same axis as the
+   host policies.
 
 Load accounting lives in exactly one place: LoadLedger.  Policies never
 mutate loads themselves — ``decide`` reads a loads vector; the caller
@@ -493,26 +493,24 @@ class WChoicesPolicy(PoTCPolicy):
 
 
 class _DevicePolicy(RoutingPolicy):
-    """Batch-only policy backed by a Pallas router (unit-cost messages).
+    """Batch-only policy backed by the device router (unit-cost messages).
 
-    The kernels account loads in integer message counts, so non-unit costs
-    are rejected rather than silently dropped; per-request ``decide`` is not
-    available — wrap the host WChoicesPolicy for the serving adapter and use
-    these for device-batch sweeps.
+    The device router accounts loads in integer message counts, so non-unit
+    costs are rejected rather than silently dropped; per-request ``decide``
+    is not available — wrap the host WChoicesPolicy for the serving adapter
+    and use these for device-batch sweeps.
     """
 
     per_request = False
 
     def __init__(self, n_replicas: int, d: int = 2, seed: int = 0,
                  capacity: int = 1024, theta: Optional[float] = None,
-                 min_count: int = 8, block: int = 128,
-                 interpret: Optional[bool] = None):
+                 min_count: int = 8, block: int = 128):
         super().__init__(n_replicas, d=d, seed=seed)
         self.capacity = capacity
         self.theta = theta
         self.min_count = min_count
         self.block = block
-        self.interpret = interpret
 
     def decide(self, key: int, loads: np.ndarray,
                alive: Optional[np.ndarray] = None,
@@ -530,8 +528,8 @@ class _DevicePolicy(RoutingPolicy):
             )
 
     def _kernel_capacities(self, capacities) -> Optional[np.ndarray]:
-        """Kernels normalize by a reciprocal-capacity row, so every capacity
-        must be strictly positive (fold zero-capacity workers out before the
+        """The router normalizes by a reciprocal-capacity row, so every
+        capacity must be strictly positive (fold zero-capacity workers out before the
         device batch; host policies handle them via the alive mask)."""
         cap = _check_capacities(self.n, capacities)
         if cap is not None and (cap <= 0).any():
@@ -542,29 +540,31 @@ class _DevicePolicy(RoutingPolicy):
 
 
 class DeviceWChoicesPolicy(_DevicePolicy):
-    """W-Choices on the in-kernel global-argmin path (kernels w_route)."""
+    """W-Choices on the device water-fill path: one shard of
+    core.partitioners.w_choices_sharded_partition (which shares its head set
+    with w_choices_partition)."""
 
     name = "w_choices_kernel"
 
     def route_batch(self, keys, costs=None, capacities=None) -> np.ndarray:
-        from repro.core.partitioners import w_choices_kernel_partition
+        from repro.core.partitioners import w_choices_sharded_partition
 
         keys = np.asarray(keys).reshape(-1)
         self._unit_costs(len(keys), costs)
         return np.asarray(
-            w_choices_kernel_partition(
+            w_choices_sharded_partition(
                 keys, self.n, d=self.d, seed=self.seed,
                 theta=self.theta, capacity=self.capacity,
-                min_count=self.min_count, block=self.block,
+                min_count=self.min_count, n_shards=1, sync_period=1,
+                block=self.block,
                 capacities=self._kernel_capacities(capacities),
-                interpret=self.interpret,
             )
         )
 
 
 class DeviceDChoicesPolicy(_DevicePolicy):
-    """D-Choices on the Pallas masked-prefix router: a thin wrapper over
-    core.partitioners.d_choices_kernel_partition (which shares its
+    """D-Choices on the device masked-prefix path: one shard of
+    core.partitioners.d_choices_sharded_partition (which shares its
     SPACESAVING pre-pass and d(k) schedule with d_choices_partition)."""
 
     name = "d_choices_kernel"
@@ -576,17 +576,17 @@ class DeviceDChoicesPolicy(_DevicePolicy):
         self.slack = slack
 
     def route_batch(self, keys, costs=None, capacities=None) -> np.ndarray:
-        from repro.core.partitioners import d_choices_kernel_partition
+        from repro.core.partitioners import d_choices_sharded_partition
 
         keys = np.asarray(keys).reshape(-1)
         self._unit_costs(len(keys), costs)
         return np.asarray(
-            d_choices_kernel_partition(
+            d_choices_sharded_partition(
                 keys, self.n, d=self.d, d_max=self.d_max, seed=self.seed,
                 theta=self.theta, capacity=self.capacity, slack=self.slack,
-                min_count=self.min_count, block=self.block,
+                min_count=self.min_count, n_shards=1, sync_period=1,
+                block=self.block,
                 capacities=self._kernel_capacities(capacities),
-                interpret=self.interpret,
             )
         )
 
@@ -596,9 +596,9 @@ class ShardedWChoicesPolicy(_DevicePolicy):
     stream splits over an ``n_shards`` ("data",) mesh, every shard routes
     against its own local loads row, and the per-shard load deltas psum
     every ``sync_period`` blocks.  ``n_shards=1, sync_period=1`` is
-    bit-exact to DeviceWChoicesPolicy's single-core kernel path; the mesh
-    is emulated (vmap + sum, bit-identical) when the host has fewer than
-    n_shards devices, so the registered policy runs anywhere."""
+    DeviceWChoicesPolicy, bit for bit; the mesh is emulated (vmap + sum,
+    bit-identical) when the host has fewer than n_shards devices, so the
+    registered policy runs anywhere."""
 
     name = "w_choices_sharded"
 

@@ -1,15 +1,14 @@
-"""Pallas TPU kernels for the compute hot-spots (pl.pallas_call + BlockSpec),
-with jnp oracles in ref.py and jit'd wrappers in ops.py.  On CPU they run in
-interpret mode (correctness); on TPU they compile natively."""
-from repro.kernels import ref
-from repro.kernels.ops import (
-    adaptive_route,
-    adaptive_route_online,
-    flash_attention,
-    interpret_mode,
-    moe_adaptive_dispatch,
-    moe_pkg_dispatch,
-    pkg_route,
-    rmsnorm,
-    w_route,
-)
+"""Device kernels and their oracles.  Import each from its own module:
+
+  route_core        the block-greedy routing core (hash, candidate fetch,
+                    masked argmin, water-fill, histogram update) that the
+                    chunk step, the sharded router and the MoE dispatch share
+  ss_update         the chunk step's per-event Space-Saving pass, one Pallas
+                    kernel per chunk
+  moe_pkg_dispatch  PKG / D- / W-Choices MoE expert dispatch (Pallas)
+  flash_attention,
+  rmsnorm           the LM scaffold's Pallas kernels
+  ref               plain jnp oracles for every routing semantics and kernel
+  platform          interpret-mode resolution: compiled on a TPU,
+                    interpreted elsewhere
+"""

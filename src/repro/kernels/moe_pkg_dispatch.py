@@ -14,9 +14,10 @@ the lane-wise argmin picks the less-loaded candidate, and the block histogram
 updates the load vector — no gathers or scatters.  The winning candidate
 column (`sel`) selects the matching gate weight.
 
-The adaptive variant is the MoE incarnation of adaptive_route_online: each
-block reads a head-table snapshot of the *expert-popularity* SPACESAVING
-summary (keys = expert ids, emitted by models.moe.expert_head_tables /
+The adaptive variant is the MoE incarnation of the chunk step's online
+D-/W-Choices (parallel/chunked_driver.py): each block reads a head-table
+snapshot of the *expert-popularity* SPACESAVING summary (keys = expert ids,
+emitted by models.moe.expert_head_tables /
 core.estimation.online_head_tables over the stream of router-preferred
 experts).  A token whose preferred expert is hot gets more candidate lanes
 (D-Choices: d(e) of its d_max router-ranked experts) or, with w_mode=True and

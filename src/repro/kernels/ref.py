@@ -1,9 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+"""Pure-jnp oracles for every device router and Pallas kernel (the allclose
+ground truth).
 
-Each `ref_*` mirrors the kernel contract exactly, including tie-breaking
+Each `ref_*` mirrors its executor's contract exactly, including tie-breaking
 (argmin -> first candidate) and block-staleness semantics of the PKG routers.
 The routing oracles are all built on kernels/route_core.oracle_block_step —
-the gather-based host twin of the kernels' route_block — so the mask /
+the gather-based host twin of the executors' route_block — so the mask /
 W-sentinel / water-fill / tie-break semantics are SHARED with the kernels
 (one implementation, it cannot drift) while the load fetch and the W pick
 deliberately use plain indexing instead of the kernels' one-hot matmuls:
@@ -23,7 +24,7 @@ from repro.kernels.route_core import head_table_ncand, oracle_block_step
 
 def _ref_inv_cap(capacities, n_workers):
     """(n_workers,) f32 reciprocal-capacity row, or None — the SAME
-    1/f32(cap) the kernel wrappers form, so oracle and kernel normalize by
+    1/f32(cap) the device routers form, so oracle and router normalize by
     bit-identical factors."""
     if capacities is None:
         return None
@@ -32,7 +33,8 @@ def _ref_inv_cap(capacities, n_workers):
 
 def ref_pkg_route(keys, n_workers: int, d: int = 2, seed: int = 0,
                   chunk: int = 1024, block: int = 128, capacities=None):
-    """Chunked batch-greedy PKG (matches kernels/pkg_route.py).
+    """Chunked batch-greedy PKG: ChunkedRouter("pkg") at chunk=N, and
+    sharded_route with one epoch per chunk-sized shard.
 
     Chunks are independent local estimators; within a chunk, loads update
     every `block` keys.  Returns (assign (N,), loads (N//chunk, n_workers)).
@@ -62,9 +64,9 @@ def ref_pkg_route(keys, n_workers: int, d: int = 2, seed: int = 0,
 def ref_adaptive_route(keys, n_cand, n_workers: int, d_max: int = 4,
                        seed: int = 0, chunk: int = 1024, block: int = 128,
                        w_mode: bool = False, capacities=None):
-    """Chunked batch-greedy with per-key candidate counts
-    (matches kernels/adaptive_route.py, including the route_core MASK
-    sentinel and, with w_mode=True, the W_SENTINEL water-fill path).
+    """Chunked batch-greedy with per-key candidate counts (the contract of
+    parallel.sharded_router, including the route_core MASK sentinel and,
+    with w_mode=True, the W_SENTINEL water-fill path).
 
     Returns (assign (N,), loads (N//chunk, n_workers))."""
     N = keys.shape[0]
@@ -95,10 +97,11 @@ def ref_adaptive_route_online(keys, tbl_keys, tbl_ncand, n_workers: int,
                               d_base: int = 2, d_max: int = 8, seed: int = 0,
                               chunk: int = 1024, block: int = 128,
                               w_mode: bool = False, capacities=None):
-    """Chunked batch-greedy against per-block head tables
-    (matches kernels/adaptive_route.py::adaptive_route_online; the table
-    lookup is literally the kernels' head_table_ncand and the greedy core
-    is the shared oracle_block_step).
+    """Chunked batch-greedy against per-block head tables (at chunk=N, the
+    contract of ChunkedRouter("d_choices") with tables from
+    estimation.online_head_tables; the table lookup is literally the chunk
+    step's head_table_ncand and the greedy core is the shared
+    oracle_block_step).
 
     Returns (assign (N,), loads (N//chunk, n_workers))."""
     N = keys.shape[0]
@@ -131,8 +134,9 @@ def ref_adaptive_route_online(keys, tbl_keys, tbl_ncand, n_workers: int,
 
 def ref_w_route(keys, is_head, n_workers: int, d: int = 2, seed: int = 0,
                 chunk: int = 1024, block: int = 128, capacities=None):
-    """Oracle for kernels/adaptive_route.py::w_route: head-flagged keys take
-    the global argmin (W-Choices), tail keys PKG's d-candidate step.
+    """W-Choices with per-key head flags (sharded_route under w_mode):
+    head-flagged keys take the global argmin, tail keys PKG's d-candidate
+    step.
 
     Returns (assign (N,), loads (N//chunk, n_workers))."""
     flags = jnp.asarray(is_head).astype(jnp.int32)
@@ -146,7 +150,8 @@ def ref_w_route(keys, is_head, n_workers: int, d: int = 2, seed: int = 0,
 def ref_w_route_online(keys, tbl_keys, tbl_ncand, n_workers: int,
                        d_base: int = 2, d_max: int = 8, seed: int = 0,
                        chunk: int = 1024, block: int = 128, capacities=None):
-    """Oracle for the online W-Choices path: per-block head tables emitted by
+    """Oracle for the online W-Choices path (ChunkedRouter("w_choices")):
+    per-block head tables emitted by
     estimation.online_head_tables(any_worker=True), whose W_SENTINEL entries
     route through the global argmin.  Identical code to
     ref_adaptive_route_online with w_mode=True — the sentinel handling lives

@@ -1,12 +1,12 @@
 """THE shared block-routing core: one masked-greedy implementation for every
-Pallas router and its host oracle.
+device router and its host oracle.
 
 Three consumers share this module verbatim (DESIGN.md SS3.3 "One routing-
 kernel substrate"):
 
-  kernels/pkg_route.py          — plain 2-choice PKG over hashed candidates
-  kernels/adaptive_route.py     — D-/W-Choices with data-dependent candidate
-                                  counts / per-block head-table snapshots
+  parallel/chunked_driver.py    — the chunk step (XLA scan): PKG, D- and
+                                  W-Choices with online head tables
+  parallel/sharded_router.py    — per-key candidate counts over 1..S shards
   kernels/moe_pkg_dispatch.py   — MoE expert dispatch (PKG-PoTC and the
                                   adaptive D-/W-Choices variants), where the
                                   "workers" are experts and each token block
@@ -23,7 +23,7 @@ global argmin, add one"), each checked against the others bit for bit in
 tests/test_kernels.py.  The kernel side runs `waterfill_levels` without
 capacities (loop-free: it counts picks per integer load level) and
 `waterfill_assign` with them (sequential: one argmin per head lane); both
-lower under Mosaic, so the XLA and the Pallas callers share them.  The
+lower under Mosaic, so the XLA and the Pallas (MoE) callers share them.  The
 oracle runs the loop-free `waterfill_picks` (top_k).
 
 Vocabulary: `n_entities` is the number of routing targets — stream workers
@@ -282,7 +282,7 @@ def route_block(cand, nc, loads, *, n_entities, w_mode, inv_cap=None):
     Returns (choice (V,), sel (V,), is_w (V,) or None, new loads).  `sel` is
     the winning candidate column (MoE dispatch selects the matching gate with
     it); `is_w` flags the lanes the W path overrode (their `sel` is
-    meaningless).  Every Pallas router calls this — the callers differ ONLY
+    meaningless).  Every device router calls this — the callers differ ONLY
     in how cand/nc are produced — so sentinel/tie-break/update semantics
     cannot drift apart.
 

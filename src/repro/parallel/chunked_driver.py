@@ -1,10 +1,10 @@
 """Memory-flat chunked streaming execution over the shared route core.
 
-The one-shot Pallas routers (kernels/pkg_route.py, kernels/adaptive_route.py)
-materialize the whole stream on device and scan it once — fine at 1e5
-messages, impossible at 1e8.  This module routes *unbounded* chunk iterators
-through the SAME block-greedy core (kernels/route_core.route_block) with
-constant device memory:
+This is the one device executor for one-source routing with online head
+detection (PKG, D- and W-Choices; DESIGN.md §2); per-key candidate counts
+over 1..S sources are parallel/sharded_router.py's.  It routes *unbounded*
+chunk iterators through the shared block-greedy core
+(kernels/route_core.route_block) with constant device memory:
 
 * **One fixed-shape jitted chunk step** per static configuration, compiled
   once and reused for every chunk of the stream (the final partial chunk is
@@ -20,15 +20,15 @@ constant device memory:
 
 Bit-exactness contract (tests/test_chunked.py): routing a stream through any
 chunk size — including chunk sizes that force a padded final chunk — yields
-the SAME assignment as the one-shot scan, because the carry (integer counts
-in f32 + the OnlineSS summary arrays) is exactly the scan state the one-shot
-path threads internally, and pad lanes are masked out of the histogram, the
-tracker, and the water-fill (they can never perturb a real decision).  The
-one-shot references are:
+the SAME assignment as the one-shot oracle, because the carry (integer
+counts in f32 + the OnlineSS summary arrays) is exactly the scan state the
+one-shot oracle threads internally, and pad lanes are masked out of the
+histogram, the tracker, and the water-fill (they can never perturb a real
+decision).  The one-shot oracles (kernels/ref.py) are:
 
-  pkg        -> kernels.pkg_route(chunk=N)   (same block size)
-  d_choices  -> estimation.online_head_tables + adaptive_route_online
-  w_choices  -> same, with any_worker head tables and w_mode=True
+  pkg        -> ref_pkg_route(chunk=N)   (same block size)
+  d_choices  -> estimation.online_head_tables + ref_adaptive_route_online
+  w_choices  -> same, with any_worker head tables (ref_w_route_online)
 
 Per-block semantics for the adaptive policies mirror ``online_head_tables``
 exactly: emit the head table from the summary *before* the block (stale by
@@ -237,7 +237,7 @@ class ChunkedRouter:
     multiples of `block` to keep block boundaries aligned across runs).
 
     `capacities` ((n_workers,) strictly positive) switches every argmin to
-    capacity-normalized loads, exactly as the one-shot kernels do.
+    capacity-normalized loads, exactly as the one-shot oracles do.
     """
 
     def __init__(

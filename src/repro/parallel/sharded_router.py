@@ -4,20 +4,22 @@ The paper's two enabling techniques — key splitting and *local* load
 estimation — are exactly the contract a device mesh needs: each source can
 route with only its own view of the worker loads, so the key stream shards
 over a 1-D ``("data",)`` mesh with ``shard_map`` and every shard runs the
-SAME block-greedy core as the single-core Pallas routers
+SAME block-greedy core as the chunk step and the MoE dispatch
 (kernels/route_core.route_block — one implementation, zero drift) against
 its own local copy of the ``(1, n_workers)`` loads row.
 
-Staleness contract, lifted across chips: the single-core router's loads are
+Staleness contract, lifted across chips: a single estimator's loads are
 stale by < ``block`` messages (DESIGN.md §2); here each shard's view of the
 OTHER shards' loads is additionally stale by < one *load-sync epoch* =
 ``sync_period`` blocks.  Every ``sync_period`` blocks the per-shard load
 deltas are ``psum``-ed over the mesh, so every shard re-synchronizes on the
 global histogram — the paper's local-estimation trick with periodic
-reconciliation.  ``n_shards=1, sync_period=1`` replays the single-core
-kernel bit-exactly (the differential contract in
-tests/test_sharded_router.py); larger ``sync_period`` trades collective
-bytes for imbalance, a curve bench_sharded_router.py measures.
+reconciliation.  ``n_shards=1`` is one estimator over the whole stream,
+bit-exact to the ref.py block-greedy oracles (the differential contract in
+tests/test_sharded_router.py); one epoch per shard (``sync_period`` =
+chunk/block) is one independent estimator per chunk, the oracles' ``chunk``
+argument.  Larger ``sync_period`` trades collective bytes for imbalance, a
+curve bench_sharded_router.py measures.
 
 Two formulations, bit-identical by construction (integer counts in f32 are
 exact under any reduction order):
@@ -42,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.estimation import W_SENTINEL
 from repro.core.hashing import derive_seeds
 from repro.kernels.route_core import hash_candidates, route_block
 
@@ -51,8 +52,6 @@ __all__ = [
     "shard_grid",
     "sharded_route",
     "ref_sharded_route",
-    "sharded_pkg_route",
-    "sharded_w_route",
     "routed_step_roofline",
 ]
 
@@ -243,21 +242,20 @@ def sharded_route(
     with its own local loads row; every ``sync_period`` blocks the per-shard
     deltas are psum-ed (the load-sync epoch).  ``n_cand`` is the per-message
     candidate count (None: all d_max lanes live, plain PKG; W_SENTINEL
-    entries take the global-argmin W path under ``w_mode=True`` — same
-    contract as kernels.adaptive_route).  Returns (assign (N,) int32,
+    entries take the global-argmin W path under ``w_mode=True`` — the
+    contract of ref.ref_adaptive_route).  Returns (assign (N,) int32,
     final synced global loads (n_workers,) f32).
 
     ``capacities`` ((n_workers,) strictly positive) makes every shard's
     argmin capacity-normalized — each shard receives the same replicated
-    reciprocal-capacity row the single-core kernels consume.
+    reciprocal-capacity row the chunk step consumes.
     ``shard_weights`` ((n_shards,) non-negative f32) scales each shard's
     load-sync delta before the psum, weighting the synced histogram by
     per-shard capacity; None or all-ones is bit-exact to the unweighted
     sync (integer counts in f32).
 
-    ``n_shards=1, sync_period=1`` is bit-exact to the single-core Pallas
-    routers (pkg_route / adaptive_route / w_route) over one chunk — they all
-    call the same route_core.route_block.
+    ``n_shards=1`` is bit-exact to the ref.py oracles (ref_pkg_route /
+    ref_adaptive_route / ref_w_route) over one chunk of the whole stream.
     """
     N = keys.shape[0]
     n_epochs = _check_shapes(N, n_shards, sync_period, block)
@@ -323,21 +321,6 @@ def ref_sharded_route(
     if shard_weights is not None:
         args.append(jnp.asarray(shard_weights, jnp.float32).reshape(n_shards))
     return fn(*args)
-
-
-def sharded_pkg_route(keys, n_workers: int, d: int = 2, **kw):
-    """Plain PKG (fixed d candidates) on the sharded router."""
-    return sharded_route(keys, None, n_workers, d_max=d, **kw)
-
-
-def sharded_w_route(keys, is_head, n_workers: int, d: int = 2, **kw):
-    """W-Choices on the sharded router: head keys (is_head != 0) go to the
-    shard-locally least-loaded worker via the water-fill global argmin; tail
-    keys take PKG's exact d-candidate step.  Same flag convention as
-    kernels.adaptive_route.w_route."""
-    flags = jnp.asarray(is_head).astype(jnp.int32)
-    n_cand = jnp.where(flags != 0, jnp.int32(W_SENTINEL), jnp.int32(d))
-    return sharded_route(keys, n_cand, n_workers, d_max=d, w_mode=True, **kw)
 
 
 def routed_step_roofline(

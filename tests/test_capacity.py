@@ -1,8 +1,8 @@
 """Per-worker capacity weighting: the cross-cutting contracts (PR 9).
 
 The capacity extension (arXiv 1705.09073) touches every routing layer —
-LoadLedger, all registered policies, all registered partitioners, the Pallas
-kernels, the sharded router — and its safety story is a single invariant:
+LoadLedger, all registered policies, all registered partitioners, the chunk
+step, the sharded router — and its safety story is a single invariant:
 
   *capacities=None and uniform capacities are BIT-EXACT to the unweighted
   path, on every registered entry point.*

@@ -2,13 +2,13 @@
 streaming simulate_serving).
 
 The load-bearing contract: routing a stream through the chunked driver is
-BIT-EXACT to the one-shot scan for EVERY chunk size — including chunk sizes
-that force a padded final chunk — because the carried (loads, Space-Saving
-summary) tuple is exactly the scan state the one-shot path threads
-internally.  One-shot references:
+BIT-EXACT to the one-shot oracle for EVERY chunk size — including chunk
+sizes that force a padded final chunk — because the carried (loads,
+Space-Saving summary) tuple is exactly the scan state the one-shot oracle
+threads internally.  One-shot oracles (kernels/ref.py, chunk = N):
 
-  pkg        -> kernels.pkg_route (same block size)
-  d_choices  -> estimation.online_head_tables + adaptive_route_online
+  pkg        -> ref_pkg_route (same block size)
+  d_choices  -> estimation.online_head_tables + ref_adaptive_route_online
   w_choices  -> same with any_worker tables and w_mode=True
 
 Plus: kill/revive invariance across chunk sizes, the Space-Saving carry
@@ -48,8 +48,7 @@ from repro.core.traces import (
     read_wikipedia_pagecounts,
     trace_chunks,
 )
-from repro.kernels.adaptive_route import adaptive_route_online
-from repro.kernels.pkg_route import pkg_route
+from repro.kernels import ref as oracle
 from repro.parallel.chunked_driver import (
     ChunkedRouter,
     ChunkedShardedRouter,
@@ -79,8 +78,8 @@ def test_pkg_chunked_eq_oneshot_any_chunk(c):
     chunked driver's final chunk — the full {1, 7, 128, n} sweep."""
     n = 1000
     keys = zipf_stream(n, 300, 1.4, seed=1)
-    ref = np.asarray(pkg_route(jnp.asarray(keys), W, d=2, seed=3,
-                               chunk=n, block=1)[0])
+    ref = np.asarray(oracle.ref_pkg_route(jnp.asarray(keys), W, d=2, seed=3,
+                                          chunk=n, block=1)[0])
     r = ChunkedRouter(W, "pkg", chunk=c, block=1, seed=3)
     got = r.route_stream(keys)
     assert np.array_equal(got, ref)
@@ -92,13 +91,13 @@ def test_pkg_chunked_eq_oneshot_any_chunk(c):
 def test_pkg_chunked_eq_oneshot_block128(c):
     n = 1024
     keys = zipf_stream(n, 300, 1.6, seed=2)
-    ref = np.asarray(pkg_route(jnp.asarray(keys), W, d=2, seed=0,
-                               chunk=n, block=128)[0])
+    ref = np.asarray(oracle.ref_pkg_route(jnp.asarray(keys), W, d=2, seed=0,
+                                          chunk=n, block=128)[0])
     got = ChunkedRouter(W, "pkg", chunk=c, block=128, seed=0).route_stream(keys)
     assert np.array_equal(got, ref)
 
 
-def _adaptive_ref(keys, n_workers, policy, block, d_max=8):
+def _adaptive_ref(keys, n_workers, policy, block, d_max=8, capacities=None):
     w_mode = policy == "w_choices"
     kj = jnp.asarray(keys)
     tk, tn = online_head_tables(
@@ -106,9 +105,9 @@ def _adaptive_ref(keys, n_workers, policy, block, d_max=8):
         decay_period=DECAY, any_worker=w_mode,
     )
     lanes = 2 if w_mode else d_max
-    return np.asarray(adaptive_route_online(
+    return np.asarray(oracle.ref_adaptive_route_online(
         kj, tk, tn, n_workers, d_base=2, d_max=lanes, seed=0,
-        chunk=len(keys), block=block, w_mode=w_mode,
+        chunk=len(keys), block=block, w_mode=w_mode, capacities=capacities,
     )[0])
 
 
@@ -151,13 +150,23 @@ def test_ss_carry_handoff_under_drift():
         assert np.array_equal(got, ref), policy
 
 
-def test_capacities_chunked_eq_oneshot():
+@pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
+def test_capacities_chunked_eq_oneshot(policy):
+    """Capacity-normalized argmins (and, for W-Choices, the sequential
+    water-fill that capacities select) carry across chunks bit-exactly."""
     n = 1024
     cap = np.array([1.0 + (i % 4) for i in range(W)], np.float32)
     keys = zipf_stream(n, 300, 1.6, seed=7)
-    ref = np.asarray(pkg_route(jnp.asarray(keys), W, d=2, seed=0, chunk=n,
-                               block=128, capacities=jnp.asarray(cap))[0])
-    got = ChunkedRouter(W, "pkg", chunk=256, block=128, seed=0,
+    if policy == "pkg":
+        ref = np.asarray(oracle.ref_pkg_route(
+            jnp.asarray(keys), W, d=2, seed=0, chunk=n, block=128,
+            capacities=jnp.asarray(cap),
+        )[0])
+    else:
+        ref = _adaptive_ref(keys, W, policy, block=128,
+                            capacities=jnp.asarray(cap))
+    got = ChunkedRouter(W, policy, chunk=256, block=128, seed=0, d_max=8,
+                        ss_capacity=CAP, decay_period=DECAY,
                         capacities=cap).route_stream(keys)
     assert np.array_equal(got, ref)
 
@@ -251,8 +260,8 @@ def test_chunked_partitioners_registered():
 def test_chunked_partitioner_matches_kernel():
     n = 1024
     keys = zipf_stream(n, 300, 1.6, seed=10)
-    ref = np.asarray(pkg_route(jnp.asarray(keys), W, d=2, seed=0,
-                               chunk=n, block=128)[0])
+    ref = np.asarray(oracle.ref_pkg_route(jnp.asarray(keys), W, d=2, seed=0,
+                                          chunk=n, block=128)[0])
     a = np.asarray(pkg_chunked_partition(jnp.asarray(keys), W, d=2, seed=0,
                                          chunk=256, block=128))
     assert np.array_equal(a, ref)

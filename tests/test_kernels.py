@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+"""Per-executor shape/dtype sweeps against the ref.py oracles: the chunk
+step (ChunkedRouter) and the sharded scan (ref_sharded_route) for the stream
+routers, the Pallas kernels (interpret mode on the CPU) for the rest."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,14 +9,8 @@ import pytest
 from repro.core.estimation import W_SENTINEL, online_head_tables
 from repro.core.streams import drift_stream, zipf_stream
 from repro.kernels import ref
-from repro.kernels.adaptive_route import (
-    adaptive_route,
-    adaptive_route_online,
-    w_route,
-)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch, moe_pkg_dispatch
-from repro.kernels.pkg_route import pkg_route
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.route_core import (
     MASK,
@@ -23,24 +19,59 @@ from repro.kernels.route_core import (
     waterfill_picks,
 )
 from repro.models.moe import _pkg_choose, expert_head_tables
+from repro.parallel.chunked_driver import ChunkedRouter
+from repro.parallel.sharded_router import ref_sharded_route
+
+
+def _chunked(keys, n_workers, policy, **kw):
+    """ChunkedRouter over the whole stream in 1024-event chunks: one
+    estimator, so its oracle runs at chunk=N.  Returns (assign, loads)."""
+    router = ChunkedRouter(n_workers, policy, chunk=1024, block=128, **kw)
+    return router.route_stream(np.asarray(keys)), router.loads
+
+
+def _one_shard(keys, nc, n_workers, d_max, **kw):
+    """The sharded scan at one shard: one estimator over the whole stream."""
+    return ref_sharded_route(
+        keys, nc, n_workers, d_max=d_max, n_shards=1, sync_period=1, **kw
+    )
+
+
+def _per_chunk(keys, nc, n_workers, d_max, chunk, block):
+    """One load-sync epoch per chunk-sized shard: an independent estimator
+    per chunk, the oracles' `chunk` argument."""
+    return ref_sharded_route(
+        keys, nc, n_workers, d_max=d_max, n_shards=keys.shape[0] // chunk,
+        sync_period=chunk // block, block=block,
+    )
+
+
+def _w_ncand(flags, d):
+    """Per-key candidate counts of a W-Choices head-flag vector."""
+    return jnp.where(jnp.asarray(flags) != 0, jnp.int32(W_SENTINEL), jnp.int32(d))
+
+
+def _eq(a, b):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("n_workers", [5, 16, 50, 100])
 @pytest.mark.parametrize("d", [2, 3])
 def test_pkg_route_matches_ref(n_workers, d):
     keys = jnp.asarray(zipf_stream(4096, 777, 1.1, seed=n_workers))
-    a_k, l_k = pkg_route(keys, n_workers, d=d, chunk=1024, block=128)
-    a_r, l_r = ref.ref_pkg_route(keys, n_workers, d=d, chunk=1024, block=128)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
-    np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_r))
+    a_k, l_k = _chunked(keys, n_workers, "pkg", d=d)
+    a_r, l_r = ref.ref_pkg_route(keys, n_workers, d=d, chunk=4096, block=128)
+    _eq(a_k, a_r)
+    _eq(l_k, l_r[-1])
 
 
 @pytest.mark.parametrize("chunk,block", [(512, 64), (2048, 256), (1024, 1024)])
 def test_pkg_route_chunk_block_sweep(chunk, block):
     keys = jnp.asarray(zipf_stream(4096, 333, 1.4, seed=1))
-    a_k, _ = pkg_route(keys, 12, chunk=chunk, block=block)
-    a_r, _ = ref.ref_pkg_route(keys, 12, chunk=chunk, block=block)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
+    a_k, l_k = _per_chunk(keys, None, 12, 2, chunk, block)
+    a_r, l_r = ref.ref_pkg_route(keys, 12, chunk=chunk, block=block)
+    _eq(a_k, a_r)
+    _eq(l_k, l_r.sum(axis=0))
 
 
 @pytest.mark.parametrize("n_workers", [16, 50, 100])
@@ -50,33 +81,36 @@ def test_adaptive_route_matches_ref(n_workers, d_max):
     nc = jnp.asarray(
         np.random.default_rng(n_workers).integers(1, d_max + 1, 4096, dtype=np.int32)
     )
-    a_k, l_k = adaptive_route(keys, nc, n_workers, d_max=d_max)
-    a_r, l_r = ref.ref_adaptive_route(keys, nc, n_workers, d_max=d_max)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
-    np.testing.assert_array_equal(np.asarray(l_k), np.asarray(l_r))
+    a_k, l_k = _one_shard(keys, nc, n_workers, d_max)
+    a_r, l_r = ref.ref_adaptive_route(keys, nc, n_workers, d_max=d_max, chunk=4096)
+    _eq(a_k, a_r)
+    _eq(l_k, l_r[-1])
 
 
 @pytest.mark.parametrize("chunk,block", [(512, 64), (2048, 256), (1024, 1024)])
 def test_adaptive_route_chunk_block_sweep(chunk, block):
     keys = jnp.asarray(zipf_stream(4096, 333, 1.4, seed=1))
     nc = jnp.asarray(np.random.default_rng(2).integers(1, 5, 4096, dtype=np.int32))
-    a_k, _ = adaptive_route(keys, nc, 12, d_max=4, chunk=chunk, block=block)
+    a_k, _ = _per_chunk(keys, nc, 12, 4, chunk, block)
     a_r, _ = ref.ref_adaptive_route(keys, nc, 12, d_max=4, chunk=chunk, block=block)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
+    _eq(a_k, a_r)
 
 
 @pytest.mark.parametrize("n_workers", [16, 100])
 @pytest.mark.parametrize("capacity", [32, 64])
 def test_adaptive_route_online_matches_ref(n_workers, capacity):
-    """Head-table kernel vs oracle, tables from the real online tracker."""
+    """Chunk step vs oracle, tables from the real online tracker."""
     keys = jnp.asarray(zipf_stream(4096, 777, 1.8, seed=capacity))
     tk, tn = online_head_tables(
         keys, block=128, capacity=capacity, n_workers=n_workers, d_max=8
     )
-    a_k, l_k = adaptive_route_online(keys, tk, tn, n_workers, d_max=8)
-    a_r, l_r = ref.ref_adaptive_route_online(keys, tk, tn, n_workers, d_max=8)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
-    np.testing.assert_array_equal(np.asarray(l_k), np.asarray(l_r))
+    a_k, l_k = _chunked(keys, n_workers, "d_choices", d_max=8,
+                        ss_capacity=capacity)
+    a_r, l_r = ref.ref_adaptive_route_online(
+        keys, tk, tn, n_workers, d_max=8, chunk=4096
+    )
+    _eq(a_k, a_r)
+    _eq(l_k, l_r[-1])
 
 
 def test_adaptive_route_online_drift_decay_matches_ref():
@@ -85,37 +119,37 @@ def test_adaptive_route_online_drift_decay_matches_ref():
     tk, tn = online_head_tables(
         keys, block=128, capacity=64, n_workers=100, d_max=8, decay_period=2_048
     )
-    a_k, _ = adaptive_route_online(keys, tk, tn, 100, d_max=8)
-    a_r, _ = ref.ref_adaptive_route_online(keys, tk, tn, 100, d_max=8)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
+    a_k, _ = _chunked(keys, 100, "d_choices", d_max=8, ss_capacity=64,
+                      decay_period=2_048)
+    a_r, _ = ref.ref_adaptive_route_online(keys, tk, tn, 100, d_max=8, chunk=8192)
+    _eq(a_k, a_r)
 
 
 def test_adaptive_route_online_empty_table_is_pkg_route():
-    """All-miss head tables (staleness degenerate case) reduce to plain PKG:
-    a lookup miss yields d_base candidates and the seed family is prefix-
-    stable, so assignments match pkg_route bit-exactly."""
-    keys = jnp.asarray(zipf_stream(4096, 500, 1.2, seed=3))
-    nblk = 4096 // 128
-    tk = jnp.full((nblk, 32), -1, jnp.int32)
-    tn = jnp.zeros((nblk, 32), jnp.int32)
-    a_o, l_o = adaptive_route_online(keys, tk, tn, 16, d_base=2, d_max=4)
-    a_p, l_p = pkg_route(keys, 16, d=2)
-    np.testing.assert_array_equal(np.asarray(a_o), np.asarray(a_p))
-    np.testing.assert_array_equal(np.asarray(l_o), np.asarray(l_p))
+    """A stream with no head key (every key once) leaves every head-table
+    lookup a miss: a miss yields d_base candidates and the seed family is
+    prefix-stable, so D-Choices routes as plain PKG, bit for bit."""
+    keys = jnp.asarray(
+        np.random.default_rng(3).permutation(1 << 20)[:4096].astype(np.int32)
+    )
+    a_o, l_o = _chunked(keys, 16, "d_choices", d_max=4)
+    a_p, l_p = ref.ref_pkg_route(keys, 16, d=2, chunk=4096)
+    _eq(a_o, a_p)
+    _eq(l_o, l_p[-1])
 
 
 def test_adaptive_route_all_two_choices_is_pkg_route():
     """n_cand == 2 everywhere reduces to the plain PKG router bit-exactly."""
     keys = jnp.asarray(zipf_stream(4096, 500, 1.2, seed=3))
     nc = jnp.full(4096, 2, jnp.int32)
-    a_a, l_a = adaptive_route(keys, nc, 16, d_max=4)
-    a_p, l_p = pkg_route(keys, 16, d=2)
-    np.testing.assert_array_equal(np.asarray(a_a), np.asarray(a_p))
-    np.testing.assert_array_equal(np.asarray(l_a), np.asarray(l_p))
+    a_a, l_a = _one_shard(keys, nc, 16, 4)
+    a_p, l_p = ref.ref_pkg_route(keys, 16, d=2, chunk=4096)
+    _eq(a_a, a_p)
+    _eq(l_a, l_p[-1])
 
 
 # ---------------------------------------------------------------------------
-# W-Choices global-argmin path (DESIGN.md SS3.3 "In-kernel W-Choices")
+# W-Choices global-argmin path (DESIGN.md SS3.3 "On-device W-Choices")
 # ---------------------------------------------------------------------------
 
 
@@ -218,16 +252,16 @@ def test_waterfill_assign_equals_sequential_argmin(fill, case, weighted,
 @pytest.mark.parametrize("n_workers", [7, 50, 100, 200])
 @pytest.mark.parametrize("d", [2, 4])
 def test_w_route_matches_ref(n_workers, d):
-    """Kernel vs oracle with random head flags: assignments AND loads bit-
-    equal, across W not a power of two and W above the 128-lane block."""
+    """Sharded scan vs oracle with random head flags: assignments AND loads
+    bit-equal, across W not a power of two and W above the 128-lane block."""
     keys = jnp.asarray(zipf_stream(2048, 500, 1.6, seed=n_workers))
     flags = jnp.asarray(
         (np.random.default_rng(d).random(2048) < 0.25).astype(np.int32)
     )
-    a_k, l_k = w_route(keys, flags, n_workers, d=d, chunk=1024, block=128)
-    a_r, l_r = ref.ref_w_route(keys, flags, n_workers, d=d, chunk=1024, block=128)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
-    np.testing.assert_array_equal(np.asarray(l_k), np.asarray(l_r))
+    a_k, l_k = _one_shard(keys, _w_ncand(flags, d), n_workers, d, w_mode=True)
+    a_r, l_r = ref.ref_w_route(keys, flags, n_workers, d=d, chunk=2048, block=128)
+    _eq(a_k, a_r)
+    _eq(l_k, l_r[-1])
 
 
 def test_w_route_all_tail_is_pkg_route():
@@ -235,22 +269,22 @@ def test_w_route_all_tail_is_pkg_route():
     IS the plain PKG router, message for message."""
     keys = jnp.asarray(zipf_stream(2048, 500, 1.2, seed=3))
     flags = jnp.zeros(2048, jnp.int32)
-    a_w, l_w = w_route(keys, flags, 16, d=2)
-    a_p, l_p = pkg_route(keys, 16, d=2)
-    np.testing.assert_array_equal(np.asarray(a_w), np.asarray(a_p))
-    np.testing.assert_array_equal(np.asarray(l_w), np.asarray(l_p))
+    a_w, l_w = _one_shard(keys, _w_ncand(flags, 2), 16, 2, w_mode=True)
+    a_p, l_p = ref.ref_pkg_route(keys, 16, d=2, chunk=2048)
+    _eq(a_w, a_p)
+    _eq(l_w, l_p[-1])
 
 
 @pytest.mark.parametrize("n_workers", [13, 100])
 def test_w_route_all_head_waterfills_perfectly(n_workers):
-    """Every message head-flagged -> the whole chunk is one global water-fill:
-    worker loads differ by at most 1, and the kernel still matches its
-    oracle bit-exactly."""
+    """Every message head-flagged -> the whole stream is one global
+    water-fill: worker loads differ by at most 1, and the router still
+    matches its oracle bit-exactly."""
     keys = jnp.asarray(zipf_stream(1024, 50, 1.5, seed=9))
     flags = jnp.ones(1024, jnp.int32)
-    a_k, _ = w_route(keys, flags, n_workers, chunk=1024, block=128)
+    a_k, _ = _one_shard(keys, _w_ncand(flags, 2), n_workers, 2, w_mode=True)
     a_r, _ = ref.ref_w_route(keys, flags, n_workers, chunk=1024, block=128)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
+    _eq(a_k, a_r)
     loads = np.bincount(np.asarray(a_k), minlength=n_workers)
     assert loads.max() - loads.min() <= 1
 
@@ -262,15 +296,13 @@ def test_w_route_tie_break_deterministic_at_equal_loads():
     W = 16
     keys = jnp.asarray(zipf_stream(1024, 50, 1.5, seed=1))
     flags = jnp.ones(1024, jnp.int32)
-    a, _ = w_route(keys, flags, W, chunk=1024, block=128)
-    np.testing.assert_array_equal(
-        np.asarray(a)[:128], np.arange(128, dtype=np.int32) % W
-    )
+    a, _ = _one_shard(keys, _w_ncand(flags, 2), W, 2, w_mode=True)
+    _eq(np.asarray(a)[:128], np.arange(128, dtype=np.int32) % W)
 
 
 def test_w_route_block1_equals_w_choices_partition():
-    """THE differential contract: with block=1 (no staleness) and a single
-    chunk, the in-kernel W-Choices path reproduces the sequential
+    """THE differential contract: with block=1 (no staleness) and one
+    estimator, the device W-Choices path reproduces the sequential
     w_choices_partition bit-exactly given the same head set."""
     from repro.core.estimation import SpaceSavingTracker, head_threshold
     from repro.core.partitioners import _head_lookup, w_choices_partition
@@ -285,60 +317,63 @@ def test_w_route_block1_equals_w_choices_partition():
         keys_np.astype(np.int64), head_ids, np.ones(len(head_ids), np.int32), 0
     )
     a_seq = np.asarray(w_choices_partition(keys_np, W, capacity=cap))
-    a_krn, _ = w_route(
-        jnp.asarray(keys_np), jnp.asarray(flags), W, chunk=2048, block=1
+    a_dev, _ = _one_shard(
+        jnp.asarray(keys_np), _w_ncand(flags, 2), W, 2, w_mode=True, block=1
     )
-    np.testing.assert_array_equal(a_seq, np.asarray(a_krn))
+    _eq(a_seq, a_dev)
 
 
 def test_w_choices_kernel_partition_registered_and_bit_exact_at_block1():
-    """The registered partitioner wraps the same contract end to end (its own
-    tracker pre-pass included) and is reachable through PARTITIONERS."""
-    from repro.core.partitioners import PARTITIONERS, w_choices_partition
+    """The registered one-shard partitioner wraps the same contract end to
+    end (its own tracker pre-pass included) and is reachable through
+    PARTITIONERS."""
+    from repro.core.partitioners import (
+        PARTITIONERS,
+        w_choices_partition,
+        w_choices_sharded_partition,
+    )
 
-    assert PARTITIONERS["w_choices_kernel"] is not None
+    assert PARTITIONERS["w_choices_sharded"] is w_choices_sharded_partition
     W, cap = 100, 256
     keys_np = zipf_stream(1500, 400, 1.8, seed=7).astype(np.int32)  # ragged m
     a_seq = np.asarray(w_choices_partition(keys_np, W, capacity=cap))
-    a_krn = np.asarray(
-        PARTITIONERS["w_choices_kernel"](
-            keys_np, W, capacity=cap, chunk=1536, block=1
-        )
+    a_dev = np.asarray(
+        PARTITIONERS["w_choices_sharded"](keys_np, W, capacity=cap, block=1)
     )
-    np.testing.assert_array_equal(a_seq, a_krn)
+    _eq(a_seq, a_dev)
 
 
 @pytest.mark.parametrize("n_workers", [50, 100])
 def test_adaptive_route_online_any_worker_matches_ref(n_workers):
     """Online W-Choices: sentinel head tables flow through head_table_ncand
-    unclipped and the kernel matches ref_w_route_online bit-exactly."""
+    unclipped and the chunk step matches ref_w_route_online bit-exactly."""
     keys = jnp.asarray(drift_stream(4096, 800, 1.8, half_life=2048, seed=2))
     tk, tn = online_head_tables(
         keys, block=128, capacity=64, n_workers=n_workers, d=2, d_max=2,
         any_worker=True,
     )
     assert (np.asarray(tn) == int(W_SENTINEL)).any(), "no head slot emitted"
-    a_k, l_k = adaptive_route_online(
-        keys, tk, tn, n_workers, d_base=2, d_max=2, w_mode=True
+    a_k, l_k = _chunked(keys, n_workers, "w_choices", d=2, ss_capacity=64)
+    a_r, l_r = ref.ref_w_route_online(
+        keys, tk, tn, n_workers, d_base=2, d_max=2, chunk=4096
     )
-    a_r, l_r = ref.ref_w_route_online(keys, tk, tn, n_workers, d_base=2, d_max=2)
-    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_r))
-    np.testing.assert_array_equal(np.asarray(l_k), np.asarray(l_r))
+    _eq(a_k, a_r)
+    _eq(l_k, l_r[-1])
 
 
 def test_w_mode_off_matches_on_without_sentinels():
     """w_mode is a perf switch, not a semantics switch: sentinel-free
     candidate counts route bit-identically with the W path compiled out,
-    kernel and oracle both."""
+    sharded scan and oracle both."""
     keys = jnp.asarray(zipf_stream(2048, 500, 1.4, seed=4))
     nc = jnp.asarray(np.random.default_rng(0).integers(1, 5, 2048, dtype=np.int32))
-    a_on, l_on = adaptive_route(keys, nc, 32, d_max=4, w_mode=True)
-    a_off, l_off = adaptive_route(keys, nc, 32, d_max=4, w_mode=False)
-    np.testing.assert_array_equal(np.asarray(a_on), np.asarray(a_off))
-    np.testing.assert_array_equal(np.asarray(l_on), np.asarray(l_off))
+    a_on, l_on = _one_shard(keys, nc, 32, 4, w_mode=True)
+    a_off, l_off = _one_shard(keys, nc, 32, 4, w_mode=False)
+    _eq(a_on, a_off)
+    _eq(l_on, l_off)
     r_on, _ = ref.ref_adaptive_route(keys, nc, 32, d_max=4, w_mode=True)
     r_off, _ = ref.ref_adaptive_route(keys, nc, 32, d_max=4, w_mode=False)
-    np.testing.assert_array_equal(np.asarray(r_on), np.asarray(r_off))
+    _eq(r_on, r_off)
 
 
 @pytest.mark.parametrize("T,k,E,block", [(512, 1, 8, 128), (1024, 2, 16, 256), (2048, 8, 64, 512)])

@@ -30,8 +30,8 @@ from repro.core import (
     pkg_partition,
     pkg_partition_batched,
     shuffle_partition,
-    w_choices_kernel_partition,
     w_choices_partition,
+    w_choices_sharded_partition,
     zipf_stream,
 )
 from repro.core.metrics import avg_imbalance_fraction, final_imbalance_fraction
@@ -90,7 +90,7 @@ def test_adaptive_equals_pkg_without_head_keys():
 
 @pytest.mark.parametrize("name", ["W100_z1.6", "W100_z2.0"])
 def test_w_choices_kernel_near_perfect_on_scale_scenarios(name):
-    """(d) for the device path: the in-kernel W router (default block=128,
+    """(d) for the device path: the one-shard W router (default block=128,
     global-argmin water-fill) keeps the near-perfect balance of the
     sequential W-Choices where PKG explodes — the gap ROADMAP open item 1
     existed for."""
@@ -99,7 +99,7 @@ def test_w_choices_kernel_near_perfect_on_scale_scenarios(name):
     W = sc.n_workers
     pkg = final_imbalance_fraction(np.asarray(pkg_partition(jnp.asarray(keys), W)), W)
     wk = final_imbalance_fraction(
-        np.asarray(w_choices_kernel_partition(keys, W)), W
+        np.asarray(w_choices_sharded_partition(keys, W)), W
     )
     assert wk < pkg / 10, (name, wk, pkg)
     assert wk < 5e-3, (name, wk)

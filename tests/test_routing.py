@@ -213,9 +213,9 @@ def test_device_policy_rejects_per_request_and_costs():
 
 
 def test_device_w_policy_matches_host_w_partitioner():
-    """The registered device-backed W policy rides the Pallas kernel; at
-    block=1 the kernel is bit-exact to w_choices_partition, which shares its
-    head set with the host batch path."""
+    """The registered device-backed W policy rides the one-shard sharded
+    scan; at block=1 it is bit-exact to w_choices_partition, which shares
+    its head set with the host batch path."""
     from repro.core.partitioners import w_choices_partition
 
     keys = zipf_stream(1_024, 200, 1.6, seed=0)
@@ -229,7 +229,8 @@ def test_device_w_policy_matches_host_w_partitioner():
 
 
 def test_device_d_policy_matches_host_d_partitioner():
-    """adaptive_route at block=1 == d_choices_partition (same pre-pass)."""
+    """The one-shard sharded scan at block=1 == d_choices_partition (same
+    pre-pass)."""
     from repro.core.partitioners import d_choices_partition
 
     keys = zipf_stream(1_024, 200, 1.6, seed=1)

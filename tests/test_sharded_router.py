@@ -2,9 +2,10 @@
 
 The load-bearing invariants:
 
-  * n_shards=1, sync_period=1 is BIT-EXACT to the single-core Pallas routers
-    (adaptive_route / w_route) — the differential that pins the sharded scan
-    to the shared block-greedy core;
+  * n_shards=1, sync_period=1 is BIT-EXACT to the ref.py block-greedy
+    oracles (ref_pkg_route / ref_adaptive_route / ref_w_route at chunk = N),
+    with and without capacities — the differential that pins the sharded
+    scan to the shared block-greedy core;
   * the psum load-sync conserves mass: after the final epoch every shard's
     loads row equals the global assignment histogram (loads are integer
     counts in f32, so reduction order cannot matter);
@@ -34,7 +35,7 @@ from repro.core.partitioners import (
     w_choices_sharded_partition,
 )
 from repro.core.routing import make_policy
-from repro.kernels.adaptive_route import adaptive_route, w_route
+from repro.kernels import ref
 from repro.kernels.route_core import hash_candidates, oracle_block_step
 from repro.launch.mesh import make_stream_mesh
 from repro.parallel.sharded_router import (
@@ -61,44 +62,51 @@ def _w_ncand(keys, d=2):
 
 
 # ---------------------------------------------------------------------------
-# differential: 1 shard + sync_period=1 == the single-core kernels
+# differential: 1 shard + sync_period=1 == the one-estimator ref oracles
 # ---------------------------------------------------------------------------
 
+# capacities None, and a 1-4 pattern that reroutes messages
+CAPS = pytest.mark.parametrize(
+    "caps", [None, np.array([1.0 + (i % 4) for i in range(W)], np.float32)],
+    ids=["no_caps", "caps_1to4"],
+)
 
-def test_one_shard_sync1_bit_exact_pkg():
+
+def _check_one_shard(keys, nc, d_max, w_mode, caps, oracle):
+    cap = None if caps is None else jnp.asarray(caps)
+    a, loads = ref_sharded_route(keys, nc, W, d_max=d_max, n_shards=1,
+                                 sync_period=1, w_mode=w_mode, capacities=cap)
+    a_r, l_r = oracle(cap)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_r))
+    np.testing.assert_array_equal(np.asarray(loads), np.asarray(l_r[-1]))
+
+
+@CAPS
+def test_one_shard_sync1_bit_exact_pkg(caps):
     keys = _keys()
-    a, loads = ref_sharded_route(keys, None, W, d_max=2, n_shards=1,
-                                 sync_period=1)
-    nc = jnp.full((N,), 2, jnp.int32)
-    a_k, l_k = adaptive_route(keys, nc, W, d_max=2, chunk=N, block=128,
-                              interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_k))
-    np.testing.assert_array_equal(np.asarray(loads), np.asarray(l_k[-1]))
+    _check_one_shard(keys, None, 2, False, caps, lambda cap: ref.ref_pkg_route(
+        keys, W, d=2, chunk=N, block=128, capacities=cap))
 
 
-def test_one_shard_sync1_bit_exact_d_choices():
+@CAPS
+def test_one_shard_sync1_bit_exact_d_choices(caps):
     keys = _keys(seed=1)
     nc = jnp.asarray(
         np.random.default_rng(0).integers(1, 5, N).astype(np.int32)
     )
-    a, loads = ref_sharded_route(keys, nc, W, d_max=4, n_shards=1,
-                                 sync_period=1)
-    a_k, l_k = adaptive_route(keys, nc, W, d_max=4, chunk=N, block=128,
-                              interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_k))
-    np.testing.assert_array_equal(np.asarray(loads), np.asarray(l_k[-1]))
+    _check_one_shard(keys, nc, 4, False, caps, lambda cap: (
+        ref.ref_adaptive_route(keys, nc, W, d_max=4, chunk=N, block=128,
+                               capacities=cap)))
 
 
-def test_one_shard_sync1_bit_exact_w_choices():
+@CAPS
+def test_one_shard_sync1_bit_exact_w_choices(caps):
     keys = _keys(seed=2, z=1.8)
     nc = _w_ncand(keys)
-    a, loads = ref_sharded_route(keys, nc, W, d_max=2, n_shards=1,
-                                 sync_period=1, w_mode=True)
     flags = (np.asarray(nc) == int(W_SENTINEL)).astype(np.int32)
-    a_k, l_k = w_route(keys, jnp.asarray(flags), W, d=2, chunk=N, block=128,
-                       interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_k))
-    np.testing.assert_array_equal(np.asarray(loads), np.asarray(l_k[-1]))
+    _check_one_shard(keys, nc, 2, True, caps, lambda cap: ref.ref_w_route(
+        keys, jnp.asarray(flags), W, d=2, chunk=N, block=128,
+        capacities=cap))
 
 
 def test_shard_map_equals_ref_on_one_device():

@@ -20,19 +20,18 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.core.estimation import OnlineSS
 from repro.kernels import platform
-from repro.kernels.adaptive_route import adaptive_route_online, w_route
 from repro.kernels.moe_pkg_dispatch import moe_adaptive_dispatch, moe_pkg_dispatch
-from repro.kernels.pkg_route import pkg_route
 from repro.kernels.ss_update import ss_update_chunk
 from repro.parallel.chunked_driver import ChunkedRouter, clear_step_cache
-from repro.parallel.sharded_router import routed_step_roofline
+from repro.parallel.sharded_router import routed_step_roofline, sharded_route
 from repro.roofline.analysis import V5E
 
 W = 100  # the paper's largest worker count
 CHUNK = 8192  # ChunkedRouter's default chunk
-N = 262_144  # one-estimator prefix chip_smoke.py routes through the kernels
+N = 262_144  # the sharded router's one-chip stream: 2,048 vector blocks
 H = 256  # Space-Saving capacity = head-table width
 BLOCK = 128
+CAPS = np.array([1.0 + (i % 4) for i in range(W)], np.float32)  # 1-4 pattern
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +63,19 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _compile_chunk_step(chip, policy, d_max, capacities=None):
+    """ChunkedRouter's chunk step at the real chunk, for the described chip."""
+    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK, d_max=d_max,
+                           capacities=capacities)
+    carry = jax.tree.map(
+        lambda a: _spec(chip, a.shape, a.dtype), router._carry
+    )
+    seeds = _spec(chip, router._seeds.shape, router._seeds.dtype)
+    chunk = _spec(chip, (CHUNK,), jnp.int32)
+    icap = None if capacities is None else _spec(chip, (1, W), jnp.float32)
+    return router._step.lower(carry, chunk, chunk, seeds, icap).compile()
+
+
 @pytest.fixture
 def tpu_kernels(monkeypatch):
     """Kernels called with `interpret=None` compile for the TPU during the
@@ -88,13 +100,7 @@ def test_chunk_step_compiles(chip, tpu_kernels, policy, d_max):
     kernel.  D-Choices also at d_max = W, the benchmark's wp_dchoices_w100:
     a (128, 100) candidate table and a (12,800 x 100) one-hot fetch per
     block."""
-    router = ChunkedRouter(W, policy, chunk=CHUNK, block=BLOCK, d_max=d_max)
-    carry = jax.tree.map(
-        lambda a: _spec(chip, a.shape, a.dtype), router._carry
-    )
-    seeds = _spec(chip, router._seeds.shape, router._seeds.dtype)
-    chunk = _spec(chip, (CHUNK,), jnp.int32)
-    compiled = router._step.lower(carry, chunk, chunk, seeds, None).compile()
+    compiled = _compile_chunk_step(chip, policy, d_max)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     assert ("tpu_custom_call" in compiled.as_text()) == (policy != "pkg")
 
@@ -114,36 +120,41 @@ def test_ss_update_chunk_compiles(chip, decay_period):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_pkg_route_compiles(chip):
-    compiled = _compile(
-        lambda k: pkg_route(k, W, chunk=N, block=BLOCK, interpret=False),
-        _spec(chip, (N,), jnp.int32),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+def test_pkg_route_compiles(chip, tpu_kernels):
+    """The PKG chunk step with capacities: the capacity-normalized
+    candidate fetch, no Space-Saving kernel."""
+    compiled = _compile_chunk_step(chip, "pkg", 2, CAPS)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("w_mode,d_max", [(False, 8), (True, 2)])
-def test_adaptive_route_online_compiles(chip, w_mode, d_max):
-    tbl = _spec(chip, (N // BLOCK, H), jnp.int32)
-    compiled = _compile(
-        lambda k, tk, tn: adaptive_route_online(
-            k, tk, tn, W, d_max=d_max, chunk=N, block=BLOCK,
-            interpret=False, w_mode=w_mode,
-        ),
-        _spec(chip, (N,), jnp.int32), tbl, tbl,
-    )
+def test_adaptive_route_online_compiles(chip, tpu_kernels, w_mode, d_max):
+    """The D- and W-Choices chunk steps with capacities.  For W-Choices,
+    capacities select waterfill_assign (one argmin per head lane) inside
+    the XLA scan, which no other v5e compile covers."""
+    policy = "w_choices" if w_mode else "d_choices"
+    compiled = _compile_chunk_step(chip, policy, d_max, CAPS)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_w_route_compiles_with_capacities(chip):
+def test_w_route_compiles_with_capacities(topo, chip):
+    """sharded_route on one chip with per-key candidate counts under
+    w_mode and capacities: the one-shard W-Choices path the device-backed
+    routing policies run."""
+    mesh = Mesh(
+        np.asarray(topo.devices[:1]), ("data",), axis_types=(AxisType.Auto,)
+    )
     compiled = _compile(
-        lambda k, f, c: w_route(
-            k, f, W, chunk=CHUNK, block=BLOCK, interpret=False, capacities=c
+        lambda k, nc, c: sharded_route(
+            k, nc, W, d_max=2, block=BLOCK, w_mode=True, mesh=mesh,
+            capacities=c,
         ),
         _spec(chip, (N,), jnp.int32), _spec(chip, (N,), jnp.int32),
         _spec(chip, (W,), jnp.float32),
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 # router widths: Mixtral-8x7B routes each token to k=2 of E=8 experts,
