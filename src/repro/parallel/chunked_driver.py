@@ -14,9 +14,9 @@ chunk iterators through the shared block-greedy core
 * **A donated carry** — ``donate_argnums`` on the (loads row, Space-Saving
   summary, block counter) tuple — so the carry buffers are updated in place
   and device memory stays flat however many chunks stream through.
-* **Double-buffered ingestion**: chunk k+1 is rebuffered and shipped with an
-  async ``jax.device_put`` while chunk k's step executes, so host->device
-  transfer overlaps routing.
+* **A window of chunks in flight**: chunk k+1 is rebuffered and shipped with
+  an async ``jax.device_put`` while chunk k's step executes, and each step's
+  device->host copy starts at its dispatch, pulled _PULL_WINDOW chunks later.
 
 Bit-exactness contract (tests/test_chunked.py): routing a stream through any
 chunk size — including chunk sizes that force a padded final chunk — yields
@@ -49,22 +49,22 @@ Profiler spans (``jax.profiler.TraceAnnotation``, on the device trace's
 clock; without an active profiler session each costs only its
 constructor).  ``ChunkedRouter.route_stream`` opens ``router.stream`` per
 call and, per chunk, ``router.put`` (rebuffer copy + ``device_put``),
-``router.dispatch`` (enqueueing the step) and ``router.pull`` (trim, wait,
-device->host copy); the last two carry ``chunk=<n>`` from
-``ChunkedRouter.n_chunks``.  None of them encloses the caller's iterator or
-``on_chunk``.  Inside the step, ``jax.named_scope`` marks ``ss_head_table``
-and ``ss_update`` (adaptive policies; the latter wraps the one ``ss_update``
-kernel per chunk), ``candidate_fetch`` (every policy: the load fetch, lane
-masks and candidate argmin) and ``waterfill`` (W-Choices), the last two in
-``route_core.route_block``; the step's program keeps jit's name,
-``jit_step``.
+``router.dispatch`` (enqueueing the step and its host copy) and
+``router.pull`` (the wait for that copy; ``inflight=<n>``, the later chunks
+in flight), both with ``chunk=<n>`` from ``n_chunks``; none encloses the
+caller's iterator or ``on_chunk``.  The step's ``jax.named_scope``s:
+``ss_head_table``, ``ss_update`` (D-/W-Choices; one ``ss_update`` kernel),
+``candidate_fetch`` (every policy) and ``waterfill`` (W-Choices), the last
+two in ``route_core.route_block``; its program keeps jit's name, jit_step.
 
 Import directly (``from repro.parallel.chunked_driver import ChunkedRouter``);
 like parallel.sharding this module is not re-exported from repro.parallel.
 """
 from __future__ import annotations
 
+import time
 import warnings
+from collections import deque
 from typing import Callable, Iterable, Iterator, NamedTuple, Optional, Union
 
 import jax
@@ -223,6 +223,17 @@ def _get_step(cfg: _StepConfig) -> Callable:
     return _STEP_CACHE[cfg]
 
 
+# Chunks route_stream keeps dispatched and unpulled behind the one it pulls.
+# Each step's device->host copy starts at its dispatch, so a pull waits only
+# for a copy still unfinished _PULL_WINDOW dispatches later; each extra chunk
+# delays delivery by one step where the device sets the pace (PERF.md §6
+# has the sweep on a TPU v5e that chose it).
+_PULL_WINDOW = 2
+# A pull whose host copy is already done returns in a few µs; one that takes
+# longer than this waited on the device or the copy (`n_blocked_pulls`).
+_BLOCKED_PULL_NS = 10_000
+
+
 class ChunkedRouter:
     """Route an unbounded key stream in fixed-shape chunks, flat memory.
 
@@ -234,10 +245,16 @@ class ChunkedRouter:
     chunk size as long as padding only happens at the true end of the stream
     (route_stream rebuffers arbitrary incoming pieces into exact chunk-sized
     steps, so only its final flush pads; feed whole streams, or split at
-    multiples of `block` to keep block boundaries aligned across runs).
+    multiples of `block` to keep block boundaries aligned across runs — of
+    `chunk` where `decay_period` > 0, since pad blocks advance the block
+    counter that places the decay periods).
 
     `capacities` ((n_workers,) strictly positive) switches every argmin to
     capacity-normalized loads, exactly as the one-shot oracles do.
+
+    Counters: `n_routed` events, `n_chunks` steps dispatched, and
+    `n_blocked_pulls`, the pulls that found their chunk's host copy
+    unfinished (the host waited on the device or the copy).
     """
 
     def __init__(
@@ -300,6 +317,7 @@ class ChunkedRouter:
         self._killed: dict[int, float] = {}
         self.n_routed = 0
         self.n_chunks = 0  # chunk steps dispatched; the spans' `chunk` id
+        self.n_blocked_pulls = 0
 
     # -- observability ------------------------------------------------------
 
@@ -389,17 +407,21 @@ class ChunkedRouter:
     ) -> Union[np.ndarray, int]:
         """Route a stream given as one array or an iterator of arrays.
 
-        Double-buffered: while chunk k's step runs on device, chunk k+1 is
-        rebuffered and device_put, and chunk k-1's assignments are pulled to
-        host.  Returns the concatenated assignment array — or, with
-        `on_chunk` (called with each piece's (n_valid,) int32 assignments in
-        order), just the number of events routed, so a 1e8-event run never
-        holds more than one chunk of output (flat RSS).
+        A window of chunks in flight: while chunk k's step runs on device,
+        chunk k+1 is rebuffered and device_put.  Each step's device->host
+        copy starts at its dispatch, and a chunk is pulled only once
+        _PULL_WINDOW later chunks have been dispatched (all are drained
+        before returning), so the host rarely waits on a copy while the
+        device idles.  Returns the concatenated assignment array — or, with
+        `on_chunk` (called with each piece's (n_valid,) int32 assignments,
+        exactly once each and in stream order), just the number of events
+        routed, so a 1e8-event run never holds more than a window of output
+        (flat RSS).
         """
         if isinstance(chunks, np.ndarray) or not hasattr(chunks, "__iter__"):
             chunks = [np.asarray(chunks)]
         outs: Optional[list] = [] if on_chunk is None else None
-        pending = None
+        inflight: deque = deque()  # (choices, n_valid, chunk id), oldest first
         n = 0
         with TraceAnnotation("router.stream"):
             it = self._device_pieces(chunks)
@@ -410,14 +432,15 @@ class ChunkedRouter:
                     self._carry, choices = self._step(
                         self._carry, keys_d, valid_d, self._seeds, self._icap
                     )
-                cur = next(it, None)  # prefetch overlaps the async step above
-                if pending is not None:
-                    self._pull(pending, outs, on_chunk)
-                pending = (choices, n_valid, self.n_chunks)
+                    choices.copy_to_host_async()
+                inflight.append((choices, n_valid, self.n_chunks))
                 self.n_chunks += 1
                 n += n_valid
-            if pending is not None:
-                self._pull(pending, outs, on_chunk)
+                cur = next(it, None)  # prefetch overlaps the async step above
+                while len(inflight) > _PULL_WINDOW:
+                    self._pull(inflight, outs, on_chunk)
+            while inflight:
+                self._pull(inflight, outs, on_chunk)
         self.n_routed += n
         if outs is not None:
             return (
@@ -425,25 +448,23 @@ class ChunkedRouter:
             )
         return n
 
-    def _pull(self, pending, outs, on_chunk) -> None:
-        """Hand a dispatched chunk's assignments on: `_emit`'s trim, wait
-        and device->host copy inside the chunk's `router.pull` span, and
-        `on_chunk` after the span has closed."""
-        choices, n_valid, chunk = pending
-        host: list = []
-        with TraceAnnotation("router.pull", chunk=chunk):
-            self._emit(
-                (choices, n_valid), outs,
-                None if on_chunk is None else host.append,
-            )
-        for a in host:
-            on_chunk(a)
+    def _pull(self, inflight: deque, outs, on_chunk) -> None:
+        """Hand the oldest chunk in flight on: the wait for its host copy
+        inside its `router.pull` span, which carries `inflight`, the later
+        chunks still in flight; then `_emit`, after the span has closed."""
+        choices, n_valid, chunk = inflight.popleft()
+        with TraceAnnotation("router.pull", chunk=chunk, inflight=len(inflight)):
+            t = time.perf_counter_ns()
+            host = np.asarray(choices, dtype=np.int32)
+            if time.perf_counter_ns() - t > _BLOCKED_PULL_NS:
+                self.n_blocked_pulls += 1
+        self._emit((host, n_valid), outs, on_chunk)
 
     @staticmethod
     def _emit(pending, outs, on_chunk) -> None:
-        choices, n_valid = pending
-        # scatter-index recovery is a trim: pads are always the tail lanes
-        a = np.asarray(choices[:n_valid], dtype=np.int32)
+        host, n_valid = pending
+        # the trim, on the host: pads are always the tail lanes
+        a = host[:n_valid]
         if on_chunk is not None:
             on_chunk(a)
         else:

@@ -17,6 +17,7 @@ generate() for every scenario type, trace-reader round-trips, the
 compile-cache recompile warning, and streaming simulate_serving ==
 array-mode aggregates.
 """
+import functools
 import os
 import sys
 import warnings
@@ -50,6 +51,7 @@ from repro.core.traces import (
 )
 from repro.kernels import ref as oracle
 from repro.parallel.chunked_driver import (
+    _PULL_WINDOW,
     ChunkedRouter,
     ChunkedShardedRouter,
     clear_step_cache,
@@ -97,12 +99,13 @@ def test_pkg_chunked_eq_oneshot_block128(c):
     assert np.array_equal(got, ref)
 
 
-def _adaptive_ref(keys, n_workers, policy, block, d_max=8, capacities=None):
+def _adaptive_ref(keys, n_workers, policy, block, d_max=8, capacities=None,
+                  decay=DECAY):
     w_mode = policy == "w_choices"
     kj = jnp.asarray(keys)
     tk, tn = online_head_tables(
         kj, block, CAP, n_workers, d=2, d_max=d_max,
-        decay_period=DECAY, any_worker=w_mode,
+        decay_period=decay, any_worker=w_mode,
     )
     lanes = 2 if w_mode else d_max
     return np.asarray(oracle.ref_adaptive_route_online(
@@ -169,6 +172,65 @@ def test_capacities_chunked_eq_oneshot(policy):
                         ss_capacity=CAP, decay_period=DECAY,
                         capacities=cap).route_stream(keys)
     assert np.array_equal(got, ref)
+
+
+# ---------------------------------------------------------------------------
+# the window of chunks in flight
+# ---------------------------------------------------------------------------
+
+D = _PULL_WINDOW
+C_WIN = 256
+N_WIN = 50
+# (chunks, events in the last one): 1, D, D+1 and 3D+1 chunks, all whole or
+# with a short, padded last piece
+WINDOW_LENGTHS = [
+    (k, tail) for k in (1, D, D + 1, 3 * D + 1) for tail in (C_WIN, 100)
+]
+
+
+@functools.lru_cache(maxsize=None)
+def _window_oracle(policy):
+    """The one-shot assignment of the longest stream.  Routing is causal
+    (a block reads the loads at its start, the summary before it, and the
+    water-fill picks in lane order), so a shorter stream's oracle is this
+    one's prefix."""
+    keys = zipf_stream((3 * D + 2) * C_WIN, 400, 1.8, seed=15)
+    if policy == "pkg":
+        ref = oracle.ref_pkg_route(jnp.asarray(keys), N_WIN, d=2, seed=0,
+                                   chunk=len(keys), block=128)[0]
+    else:
+        ref = _adaptive_ref(keys, N_WIN, policy, block=128, decay=0)
+    return keys, np.asarray(ref)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["concat", "on_chunk"])
+@pytest.mark.parametrize("k,tail", WINDOW_LENGTHS)
+@pytest.mark.parametrize("policy", ["pkg", "d_choices", "w_choices"])
+def test_window_delivery(policy, k, tail, sink):
+    """However many chunks are in flight when the stream ends, every
+    chunk's assignments come out once, in order, bit-exact to the one-shot
+    oracle, with the carry handed across two route_stream calls split at a
+    block multiple."""
+    keys, ref = _window_oracle(policy)
+    n = (k - 1) * C_WIN + tail
+    keys, ref = keys[:n], ref[:n]
+    split = n // 2 // 128 * 128
+    r = ChunkedRouter(N_WIN, policy, chunk=C_WIN, block=128, seed=0,
+                      d_max=8, ss_capacity=CAP)
+    got, n_chunks = [], 0
+    for part in (keys[:split], keys[split:]):
+        whole, rest = divmod(len(part), C_WIN)
+        n_chunks += whole + (rest > 0)
+        if sink:
+            seen = []
+            assert r.route_stream(part, on_chunk=seen.append) == len(part)
+            assert [len(a) for a in seen] == [C_WIN] * whole + [rest] * (rest > 0)
+            got += seen
+        else:
+            got.append(r.route_stream(part))
+    assert np.array_equal(np.concatenate(got), ref)
+    assert r.n_routed == n and r.n_chunks == n_chunks
+    assert np.array_equal(r.loads, np.bincount(ref, minlength=N_WIN).astype(np.float32))
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 `ChunkedRouter.route_stream` writes `router.stream`, and per chunk
 `router.put`, `router.dispatch` and `router.pull` (the last two carrying the
-chunk id) into the profiler's trace; the chunk step's program is `jit_step`
+chunk id, the pull also the chunks still in flight) into the profiler's trace; the chunk step's program is `jit_step`
 and its HLO carries the `ss_head_table`, `ss_update` and `waterfill` scopes
 where the policy runs them, and `candidate_fetch` in every policy.  Read back here from a CPU trace with
 `jax.profiler.ProfileData`, as the chip benchmark reads a TPU one.
@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.profiler import TraceAnnotation
 
-from repro.parallel.chunked_driver import ChunkedRouter
+from repro.parallel.chunked_driver import _PULL_WINDOW, ChunkedRouter
 
 W = 8
 CHUNK = 256
@@ -27,12 +27,14 @@ CHUNK_ID = ("router.dispatch", "router.pull")
 
 
 def _host_events(trace_dir):
-    """[name, start_ns, end_ns, chunk id or None] of every host event."""
+    """[name, start_ns, end_ns, chunk id or None, inflight or None] of every
+    host event."""
     (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
     data = jax.profiler.ProfileData.from_file(path)
     return [
         [e.name, e.start_ns, e.start_ns + e.duration_ns,
-         dict(e.stats)["chunk"] if e.name in CHUNK_ID else None]
+         dict(e.stats)["chunk"] if e.name in CHUNK_ID else None,
+         dict(e.stats)["inflight"] if e.name == "router.pull" else None]
         for plane in data.planes if plane.name.startswith("/host:")
         for line in plane.lines for e in line.events
     ]
@@ -63,6 +65,7 @@ def _traced_stream(tmp_path, policy, pieces):
             router.route_stream(feed(), on_chunk=sink)
     finally:
         jax.profiler.stop_trace()
+    assert 0 <= router.n_blocked_pulls <= router.n_chunks
     return router.n_chunks - warm, warm, out, _host_events(tmp_path)
 
 
@@ -70,8 +73,10 @@ def _traced_stream(tmp_path, policy, pieces):
 def test_route_stream_spans(tmp_path, policy):
     """Five pieces of uneven sizes: three whole chunks and a padded last one
     per call.  Every chunk has a put, a dispatch and a pull; dispatch and
-    pull carry the same id, counted on across calls; no router span
-    overlaps another or the caller's spans."""
+    pull carry the same id, counted on across calls; a chunk is pulled after
+    the dispatch of the _PULL_WINDOW chunks behind it (or of the call's
+    last), and its pull's `inflight` counts them; no router span overlaps
+    another or the caller's spans."""
     rng = np.random.default_rng(0)
     sizes = [300, 100, 56, 200, 150]  # 806 events: chunks of 256 x 3 + 38
     pieces = [rng.integers(0, 500, n).astype(np.int32) for n in sizes]
@@ -85,9 +90,15 @@ def test_route_stream_spans(tmp_path, policy):
     ids = list(range(warm, warm + n))
     for k in CHUNK_ID:
         assert sorted(e[3] for e in spans[k]) == ids
-    # each pull follows its chunk's dispatch
+    # each pull follows its chunk's dispatch, and those of the window behind it
     start = {k: {e[3]: e[1] for e in spans[k]} for k in CHUNK_ID}
     assert all(start["router.pull"][i] > start["router.dispatch"][i] for i in ids)
+    per_call = n // 2
+    for i in ids:
+        last = warm + ((i - warm) // per_call + 1) * per_call - 1
+        behind = min(_PULL_WINDOW, last - i)
+        assert start["router.pull"][i] > start["router.dispatch"][i + behind]
+        assert {e[3]: e[4] for e in spans["router.pull"]}[i] == behind
     # one copy span per piece fragment that lands in a chunk
     assert len(spans["router.put"]) >= n
 
